@@ -12,7 +12,7 @@ collapses the per-group loop:
     reached because routing starts at node 0), plus the per-group ``depth``
     vector. A wave's rows — any mix of pairs — route through
     ``kernels.forest_eval.predict_grouped`` in ONE launch (Pallas grid over
-    (group, row-block) on TPU, a single depth-bounded grouped traversal
+    (row-block, tree-tile) on TPU, a single depth-bounded grouped traversal
     with per-group early exit on CPU).
   - **DNN stack** — all heads' params in one vmapped pytree (leading group
     axis) with stacked z-score/target-scale stats; a wave pays ONE
@@ -106,6 +106,15 @@ class ModelBank:
     @property
     def n_groups(self) -> int:
         return len(self.pairs)
+
+    @property
+    def forest_backend(self) -> str:
+        """The forest backend this bank's waves run (``"auto"``
+        resolved against this process's JAX backend)."""
+        if self.backend != "auto":
+            return self.backend
+        from repro.kernels import forest_eval
+        return forest_eval._auto_backend()
 
     def supports(self, pairs: Iterable[Tuple[str, str]]) -> bool:
         return all(p in self.gid for p in pairs)
@@ -256,13 +265,9 @@ class ModelBank:
         """The bank as one self-contained wire value: every stacked tensor
         an inline contiguous numpy array (no shared-memory names, no jax
         leaves), ready for the shard worker codecs
-        (``repro.serve.frames``). Backend ``"auto"`` is resolved *here*,
-        parent-side, so a remote CPU worker serves the numpy traversal
-        without ever importing jax."""
-        backend = self.backend
-        if backend == "auto" and "forest" in self.members:
-            from repro.kernels import forest_eval
-            backend = forest_eval._auto_backend()
+        (``repro.serve.frames``). Shard workers are CPU-only, so the
+        payload always names the numpy forest backend, whatever the
+        parent runs."""
         return {
             "pairs": self.pairs,
             "members": self.members,
@@ -270,7 +275,7 @@ class ModelBank:
             "devices": self.devices,
             "scalers": {k: tuple(np.ascontiguousarray(a) for a in v)
                         for k, v in self.scalers.items()},
-            "backend": backend,
+            "backend": "numpy",
             "forest": (None if self.forest is None else
                        {k: np.ascontiguousarray(v)
                         for k, v in self.forest.items()}),
@@ -407,31 +412,11 @@ class ModelBank:
                     block = jnp.zeros((g_pad, r_pad, self.n_features),
                                       jnp.float32)
                     apply(params, gidx, block).block_until_ready()
-        if "forest" in self.members and self.n_features > 0:
+        if "forest" in self.members and self.n_features > 0 \
+                and self.forest_backend == "pallas":
             from repro.kernels import forest_eval
-            effective = (forest_eval._auto_backend()
-                         if self.backend == "auto" else self.backend)
-            if effective == "pallas":
-                # the grouped launch's static shapes are (row-block size,
-                # block count), both power-of-two bucketed — compile the
-                # row-concentration shapes (one group, r rows) and the
-                # group-spread shapes (g groups, 1 row each) a wave up to
-                # max_rows can produce
-                f = self.forest
-                args = (f["feat"], f["thr"], f["left"], f["right"],
-                        f["value"])
-                r = 1
-                while r <= max(max_rows, 1):
-                    forest_eval.predict_grouped(
-                        np.zeros((r, self.n_features)),
-                        np.zeros(r, np.int64), *args, depth=f["depth"],
-                        backend="pallas")
-                    r *= 2
-                g = 2
-                while g <= self.n_groups:
-                    forest_eval.predict_grouped(
-                        np.zeros((g, self.n_features)),
-                        np.arange(g, dtype=np.int64), *args,
-                        depth=f["depth"], backend="pallas")
-                    g *= 2
+            f = self.forest
+            forest_eval.warm_grouped(
+                f["feat"], f["thr"], f["left"], f["right"], f["value"],
+                n_features=self.n_features, max_rows=max(max_rows, 1))
         return time.perf_counter() - t0

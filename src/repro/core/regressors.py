@@ -392,10 +392,15 @@ def _mlp_init(seed: int, d: int, layers: Tuple[int, ...]):
 
 
 def _mlp_apply(params, x):
+    """The MLP forward pass. Matmuls run at HIGHEST precision: a TPU's
+    default float32 matmul rounds its inputs to bfloat16, and the heads
+    must compute on the chip what they compute on the CPU."""
     import jax
+    import jax.numpy as jnp
     h = x
     for i, layer in enumerate(params):
-        h = h @ layer["w"] + layer["b"]
+        h = jnp.matmul(h, layer["w"],
+                       precision=jax.lax.Precision.HIGHEST) + layer["b"]
         if i < len(params) - 1:
             h = jax.nn.relu(h)
     return h[..., 0]

@@ -23,7 +23,6 @@ from typing import Any, Callable
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 
@@ -92,9 +91,9 @@ def pipeline_apply(block_fn: Callable[[Any, jnp.ndarray], jnp.ndarray],
             jnp.where(idx == S - 1, outputs, jnp.zeros_like(outputs)), axis)
         return outputs
 
-    fn = shard_map(stage_body, mesh=mesh,
-                   in_specs=(p_specs, P()), out_specs=P(),
-                   check_rep=False)
+    fn = jax.shard_map(stage_body, mesh=mesh,
+                       in_specs=(p_specs, P()), out_specs=P(),
+                       check_vma=False)
     out = fn(stacked_params, xm)
     return out.reshape((B,) + x.shape[1:])
 

@@ -5,9 +5,10 @@ Two backends behind one ``predict``:
 
   - ``numpy``  — float64 iterative routing, the exact production CPU path
     (bit-identical per-row vs batched, which ``bench_grid`` relies on);
-  - ``pallas`` — one kernel launch per row block on TPU (float32): the
-    forest arrays sit in VMEM, a ``fori_loop`` bounded by the grown depth
-    routes all trees x rows in lockstep via ``take_along_axis`` gathers.
+  - ``pallas`` — one grouped kernel launch on TPU (float32): each grid step
+    holds one group's 8-tree tile in VMEM, and a ``fori_loop`` bounded by
+    the grown depth routes 8 trees x 128 rows in lockstep through
+    ``take_along_axis`` gathers that each stay inside one (8, 128) vreg.
 
 Both backends return per-tree LEAF VALUES ``(n_trees, n_rows)`` from their
 inner routine; the tree-mean is taken by the shared ``tree_mean`` in
@@ -30,10 +31,6 @@ from typing import Optional
 
 import numpy as np
 
-DEFAULT_BLOCK_ROWS = 256
-
-_AUTO_BACKEND: Optional[str] = None
-
 
 def tree_mean(vals: np.ndarray) -> np.ndarray:
     """Float64 mean over the tree axis of ``(n_trees, n_rows)`` leaf values,
@@ -49,15 +46,9 @@ def tree_mean(vals: np.ndarray) -> np.ndarray:
 
 
 def _auto_backend() -> str:
-    global _AUTO_BACKEND
-    if _AUTO_BACKEND is None:
-        try:
-            import jax
-            _AUTO_BACKEND = ("pallas" if jax.default_backend() == "tpu"
-                             else "numpy")
-        except Exception:  # pragma: no cover - jax is baked into the image
-            _AUTO_BACKEND = "numpy"
-    return _AUTO_BACKEND
+    """``"pallas"`` where JAX runs on a TPU, the numpy traversal elsewhere."""
+    import jax
+    return "pallas" if jax.default_backend() == "tpu" else "numpy"
 
 
 def leaf_values_numpy(X, feat, thr, left, right, value,
@@ -155,175 +146,201 @@ def leaf_values_grouped_numpy(X, gid, feat, thr, left, right, value,
     return out
 
 
-def leaf_values_pallas(X, feat, thr, left, right, value, *, depth: int,
-                       block_rows: int = DEFAULT_BLOCK_ROWS,
-                       interpret: Optional[bool] = None) -> np.ndarray:
-    """Pallas kernel: grid over row blocks, full forest per block (float32).
-
-    ``depth`` is the exact number of routing steps (``PackedForest.depth``);
-    leaves self-loop so over-iteration is harmless but wasteful.
-    """
-    import jax
-    import jax.numpy as jnp
-    from jax.experimental import pallas as pl
-
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
-    X = np.asarray(X)
-    m, d = X.shape
-    T, N = feat.shape
-    blk = max(1, min(block_rows, m))
-    pad = (-m) % blk
-    Xp = np.concatenate([X, np.zeros((pad, d), X.dtype)]) if pad else X
-
-    def kernel(x_ref, f_ref, t_ref, l_ref, r_ref, v_ref, o_ref):
-        xT = x_ref[...].T                              # (d, blk)
-        fm, tm = f_ref[...], t_ref[...]
-        lm, rm = l_ref[...], r_ref[...]
-
-        def body(_, nid):
-            f = jnp.take_along_axis(fm, nid, axis=1)   # (T, blk)
-            t = jnp.take_along_axis(tm, nid, axis=1)
-            nl = jnp.take_along_axis(lm, nid, axis=1)
-            nr = jnp.take_along_axis(rm, nid, axis=1)
-            xv = jnp.take_along_axis(xT, jnp.maximum(f, 0), axis=0)
-            return jnp.where(f >= 0, jnp.where(xv <= t, nl, nr), nid)
-
-        nid = jax.lax.fori_loop(0, depth, body,
-                                jnp.zeros((T, xT.shape[1]), jnp.int32))
-        o_ref[...] = jnp.take_along_axis(v_ref[...], nid, axis=1)
-
-    full = lambda i: (0, 0)  # noqa: E731 - forest arrays are not blocked
-    out = pl.pallas_call(
-        kernel,
-        grid=(Xp.shape[0] // blk,),
-        in_specs=[
-            pl.BlockSpec((blk, d), lambda i: (i, 0)),
-            pl.BlockSpec((T, N), full),
-            pl.BlockSpec((T, N), full),
-            pl.BlockSpec((T, N), full),
-            pl.BlockSpec((T, N), full),
-            pl.BlockSpec((T, N), full),
-        ],
-        out_specs=pl.BlockSpec((T, blk), lambda i: (0, i)),
-        out_shape=jax.ShapeDtypeStruct((T, Xp.shape[0]), jnp.float32),
-        interpret=interpret,
-    )(jnp.asarray(Xp, jnp.float32), jnp.asarray(feat, jnp.int32),
-      jnp.asarray(thr, jnp.float32), jnp.asarray(left, jnp.int32),
-      jnp.asarray(right, jnp.int32), jnp.asarray(value, jnp.float32))
-    return np.asarray(out)[:, :m]
+# Kernel tiling. Mosaic gathers (``take_along_axis``) only within one
+# (8, 128) vreg, so the kernel works on tiles of SUBLANES trees x LANES rows
+# and reads every table in vreg-sized chunks: node tables in LANES-wide
+# chunks along the node axis, the transposed feature block in SUBLANES-high
+# chunks along the feature axis.
+LANES = 128
+SUBLANES = 8
 
 
-def leaf_values_grouped_pallas(X, gid, feat, thr, left, right, value, *,
-                               depth, block_rows: int = DEFAULT_BLOCK_ROWS,
-                               interpret: Optional[bool] = None) -> np.ndarray:
-    """Grouped Pallas kernel: ONE launch over (group, row-block) pairs.
+def _round_up(n: int, k: int) -> int:
+    return -(-n // k) * k
 
-    Rows are sorted by group and padded per group to ``block_rows``
-    multiples; the grid is the flat block list and two scalar-prefetch
-    vectors steer it — ``block_gid[i]`` selects which ``(1, T, N)`` forest
-    slice block ``i``'s BlockSpec index_map DMAs into VMEM, and
-    ``block_depth[i]`` bounds its ``fori_loop`` (leaves self-loop, so a
-    shallow group simply stops routing early). The row-block size and the
-    block COUNT are both power-of-two bucketed (padding blocks carry
-    depth 0, so they route nothing) — the launch's static shapes come from
-    a bounded set and a warmed executable serves any wave mix. float32,
-    like the per-forest kernel; returns ``(T, n_rows)`` in original row
-    order.
+
+def pad_forest_stack(feat, thr, left, right, value):
+    """The kernel layout of a ``(G, T, N)`` forest stack: trees padded to a
+    multiple of SUBLANES, nodes to a multiple of LANES, int32/float32.
+    Padded nodes are leaves (``feat = -1``) that routing never enters;
+    padded trees are a single leaf of value 0 whose rows the caller drops."""
+    G, T, N = np.shape(feat)
+    Tp, Np = _round_up(T, SUBLANES), _round_up(N, LANES)
+
+    def pad(a, fill, dtype):
+        out = np.full((G, Tp, Np), fill, dtype)
+        out[:, :T, :N] = a
+        return out
+
+    return (pad(feat, -1, np.int32), pad(thr, 0, np.float32),
+            pad(left, 0, np.int32), pad(right, 0, np.int32),
+            pad(value, 0, np.float32))
+
+
+def grouped_leaf_values(block_gid, block_depth, xt, feat, thr, left, right,
+                        value, *, interpret: bool = False):
+    """The traceable grouped kernel — a function of shapes only, so it can
+    be lowered for a described chip. Grid ``(row_blocks, tree_tiles)``:
+    block ``i`` holds LANES rows of one group, ``block_gid[i]`` steers the
+    forest BlockSpecs to that group's ``(SUBLANES, N)`` tree tile and
+    ``block_depth[i]`` bounds its routing loop (padding blocks carry depth
+    0 and route nothing).
+
+    ``xt``: ``(d_pad, n_blocks * LANES)`` float32, features on sublanes,
+    rows on lanes; forest arrays in the :func:`pad_forest_stack` layout.
+    Returns ``(T_pad, n_blocks * LANES)`` float32 leaf values.
     """
     import jax
     import jax.numpy as jnp
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
-    from repro.core.regressors import bucket
+    d_pad, n_rows = xt.shape
+    _, Tp, Np = feat.shape
+    n_blocks = n_rows // LANES
 
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
-    X = np.asarray(X)
-    gid = np.asarray(gid, np.int64)
-    m, d = X.shape
-    G, T, N = feat.shape
-    depth = np.asarray(depth, np.int64)
-    if m == 0:
-        return np.empty((T, 0), np.float32)
-    blk = min(block_rows, bucket(m, 8))
-
-    # sort rows by group; pad each group's run to a block multiple, and
-    # the block list itself to a power-of-two count
-    order = np.argsort(gid, kind="stable")
-    groups, counts = np.unique(gid, return_counts=True)
-    blocks_per = -(-counts // blk)
-    n_blocks = bucket(int(blocks_per.sum()))
-    Xp = np.zeros((n_blocks * blk, d), X.dtype)
-    pos = np.empty(m, np.int64)            # padded slot of each sorted row
-    off = 0
-    starts = np.concatenate([[0], np.cumsum(counts)[:-1]])
-    block_gid = np.zeros(n_blocks, np.int32)
-    block_gid[:int(blocks_per.sum())] = np.repeat(groups, blocks_per)
-    block_depth = np.zeros(n_blocks, np.int32)
-    block_depth[:int(blocks_per.sum())] = depth[
-        block_gid[:int(blocks_per.sum())]]
-    for gi in range(len(groups)):
-        c = int(counts[gi])
-        pos[starts[gi]:starts[gi] + c] = off + np.arange(c)
-        off += int(blocks_per[gi]) * blk
-    Xp[pos] = X[order]
+    def take(chunk, idx, axis):
+        return jnp.take_along_axis(chunk, idx, axis=axis,
+                                   mode="promise_in_bounds")
 
     def kernel(g_ref, dep_ref, x_ref, f_ref, t_ref, l_ref, r_ref, v_ref,
                o_ref):
-        i = pl.program_id(0)
-        xT = x_ref[...].T                               # (d, blk)
-        fm, tm = f_ref[0], t_ref[0]
-        lm, rm = l_ref[0], r_ref[0]
+        def nodes(ref, nid):
+            # chunk c answers node ids >= c*LANES; later chunks overwrite
+            out = None
+            for c in range(Np // LANES):
+                lo = c * LANES
+                got = take(ref[0, :, lo:lo + LANES],
+                           jnp.clip(nid - lo, 0, LANES - 1), 1)
+                out = got if out is None else jnp.where(nid >= lo, got, out)
+            return out
+
+        def features(f):
+            out = None
+            for k in range(d_pad // SUBLANES):
+                lo = k * SUBLANES
+                got = take(x_ref[lo:lo + SUBLANES, :],
+                           jnp.clip(f - lo, 0, SUBLANES - 1), 0)
+                out = got if out is None else jnp.where(f >= lo, got, out)
+            return out
 
         def body(_, nid):
-            f = jnp.take_along_axis(fm, nid, axis=1)    # (T, blk)
-            t = jnp.take_along_axis(tm, nid, axis=1)
-            nl = jnp.take_along_axis(lm, nid, axis=1)
-            nr = jnp.take_along_axis(rm, nid, axis=1)
-            xv = jnp.take_along_axis(xT, jnp.maximum(f, 0), axis=0)
+            f = nodes(f_ref, nid)                      # (SUBLANES, LANES)
+            t = nodes(t_ref, nid)
+            nl = nodes(l_ref, nid)
+            nr = nodes(r_ref, nid)
+            xv = features(jnp.maximum(f, 0))
             return jnp.where(f >= 0, jnp.where(xv <= t, nl, nr), nid)
 
-        nid = jax.lax.fori_loop(0, dep_ref[i], body,
-                                jnp.zeros((T, xT.shape[1]), jnp.int32))
-        o_ref[...] = jnp.take_along_axis(v_ref[0], nid, axis=1)
+        nid = jax.lax.fori_loop(0, dep_ref[pl.program_id(0)], body,
+                                jnp.zeros((SUBLANES, LANES), jnp.int32))
+        o_ref[...] = nodes(v_ref, nid)
 
+    tile = pl.BlockSpec((1, SUBLANES, Np), lambda i, t, g, dep: (g[i], t, 0))
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
-        grid=(n_blocks,),
-        in_specs=[
-            pl.BlockSpec((blk, d), lambda i, g, dep: (i, 0)),
-            pl.BlockSpec((1, T, N), lambda i, g, dep: (g[i], 0, 0)),
-            pl.BlockSpec((1, T, N), lambda i, g, dep: (g[i], 0, 0)),
-            pl.BlockSpec((1, T, N), lambda i, g, dep: (g[i], 0, 0)),
-            pl.BlockSpec((1, T, N), lambda i, g, dep: (g[i], 0, 0)),
-            pl.BlockSpec((1, T, N), lambda i, g, dep: (g[i], 0, 0)),
-        ],
-        out_specs=pl.BlockSpec((T, blk), lambda i, g, dep: (0, i)),
+        grid=(n_blocks, Tp // SUBLANES),
+        in_specs=[pl.BlockSpec((d_pad, LANES), lambda i, t, g, dep: (0, i)),
+                  tile, tile, tile, tile, tile],
+        out_specs=pl.BlockSpec((SUBLANES, LANES),
+                               lambda i, t, g, dep: (t, i)),
     )
-    out = pl.pallas_call(
+    return pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((T, n_blocks * blk), jnp.float32),
+        out_shape=jax.ShapeDtypeStruct((Tp, n_rows), jnp.float32),
         interpret=interpret,
-    )(jnp.asarray(block_gid, jnp.int32), jnp.asarray(block_depth, jnp.int32),
-      jnp.asarray(Xp, jnp.float32), jnp.asarray(feat, jnp.int32),
-      jnp.asarray(thr, jnp.float32), jnp.asarray(left, jnp.int32),
-      jnp.asarray(right, jnp.int32), jnp.asarray(value, jnp.float32))
-    out = np.asarray(out)
+        name="forest_grouped",
+    )(block_gid, block_depth, xt, feat, thr, left, right, value)
+
+
+_GROUPED_FN = None
+
+
+def _grouped_fn():
+    """The one jitted grouped launch, keyed on shapes. Compiled for the
+    device on a TPU backend; the Pallas interpreter elsewhere (a
+    correctness tool, not a CPU fast path)."""
+    global _GROUPED_FN
+    if _GROUPED_FN is None:
+        import functools
+
+        import jax
+        _GROUPED_FN = jax.jit(functools.partial(
+            grouped_leaf_values,
+            interpret=jax.default_backend() != "tpu"))
+    return _GROUPED_FN
+
+
+def leaf_values_grouped_pallas(X, gid, feat, thr, left, right, value, *,
+                               depth) -> np.ndarray:
+    """Grouped Pallas traversal: ONE launch over (row-block, tree-tile)
+    pairs, float32. Rows are sorted by group and padded per group to LANES
+    multiples; the block COUNT is power-of-two bucketed (padding blocks
+    carry depth 0), so the launch's static shapes come from a bounded set
+    and a warmed executable serves any wave mix. Returns ``(T, n_rows)``
+    in original row order."""
+    from repro.core.regressors import bucket
+
+    X = np.asarray(X)
+    gid = np.asarray(gid, np.int64)
+    m, d = X.shape
+    T = np.shape(feat)[1]
+    depth = np.asarray(depth, np.int64)
+    if m == 0:
+        return np.empty((T, 0), np.float32)
+
+    order = np.argsort(gid, kind="stable")
+    groups, counts = np.unique(gid, return_counts=True)
+    blocks_per = -(-counts // LANES)
+    used = int(blocks_per.sum())
+    n_blocks = bucket(used)
+    block_gid = np.zeros(n_blocks, np.int32)
+    block_gid[:used] = np.repeat(groups, blocks_per)
+    block_depth = np.zeros(n_blocks, np.int32)
+    block_depth[:used] = depth[block_gid[:used]]
+    # padded column of each sorted row: its group's block run + its rank
+    run_start = np.repeat(np.cumsum(blocks_per) - blocks_per, counts) * LANES
+    rank = np.arange(m) - np.repeat(np.cumsum(counts) - counts, counts)
+    pos = run_start + rank
+    xt = np.zeros((_round_up(d, SUBLANES), n_blocks * LANES), np.float32)
+    xt[:d, pos] = X[order].T
+
+    out = np.asarray(_grouped_fn()(
+        block_gid, block_depth, xt,
+        *pad_forest_stack(feat, thr, left, right, value)))
     res = np.empty((T, m), np.float32)
-    res[:, order] = out[:, pos]
+    res[:, order] = out[:T, pos]
     return res
+
+
+def warm_grouped(feat, thr, left, right, value, *, n_features: int,
+                 max_rows: int) -> None:
+    """Compile every block-count bucket a wave of up to ``max_rows`` rows
+    can produce over this stack (each group's rows fill whole blocks, so
+    at most ``min(max_rows, G + max_rows / LANES)`` blocks)."""
+    from repro.core.regressors import bucket
+
+    G = np.shape(feat)[0]
+    padded = pad_forest_stack(feat, thr, left, right, value)
+    d_pad = _round_up(n_features, SUBLANES)
+    cap = bucket(min(max_rows, G + -(-max_rows // LANES)))
+    nb = 1
+    while nb <= cap:
+        zeros = np.zeros(nb, np.int32)
+        np.asarray(_grouped_fn()(zeros, zeros,
+                                 np.zeros((d_pad, nb * LANES), np.float32),
+                                 *padded))
+        nb *= 2
 
 
 def predict(X, feat, thr, left, right, value, *, depth: int,
             backend: str = "auto") -> np.ndarray:
     """Forest prediction = float64 mean over per-tree leaf values.
 
-    ``backend="auto"`` compiles the Pallas kernel on TPU and falls back to
-    the exact numpy traversal elsewhere (the interpreted kernel is a
-    correctness tool, not a CPU fast path).
+    ``backend="auto"`` runs the compiled Pallas kernel on TPU and the exact
+    numpy traversal elsewhere; ``"pallas"`` on a single forest is the
+    grouped kernel with one group.
     """
     if backend == "auto":
         backend = _auto_backend()
@@ -331,8 +348,11 @@ def predict(X, feat, thr, left, right, value, *, depth: int,
         vals = leaf_values_numpy(X, feat, thr, left, right, value,
                                  depth=depth)
     elif backend == "pallas":
-        vals = leaf_values_pallas(X, feat, thr, left, right, value,
-                                  depth=depth)
+        X = np.asarray(X)
+        vals = leaf_values_grouped_pallas(
+            X, np.zeros(len(X), np.int64), *(np.asarray(a)[None] for a in
+                                            (feat, thr, left, right, value)),
+            depth=[depth])
     else:
         raise ValueError(f"unknown forest_eval backend {backend!r}")
     return tree_mean(vals)

@@ -83,8 +83,10 @@ def main(argv=None):
                          "(leases + automatic respawn of dead shard "
                          "workers)")
     ap.add_argument("--strict", action="store_true",
-                    help="exit nonzero if any replay request failed "
-                         "(CI integration gate)")
+                    help="exit nonzero if any replay request failed, the "
+                         "service booted degraded, the bank build failed, "
+                         "or a requested shard plane is unavailable (CI "
+                         "and chip gate)")
     ap.add_argument("--refresh-mid-replay", action="store_true",
                     help="refit (new seed) and oracle_refreshed() halfway "
                          "through the replay — demonstrates epoch swap "
@@ -217,9 +219,18 @@ def main(argv=None):
                   f"pending {h['pending']}")
         epochs = {r["epoch"] for r in rep["results"] if r is not None}
         print(f"response epochs seen: {', '.join(sorted(epochs))}")
-        if args.strict and rep["ok"] != rep["n"]:
-            print(f"STRICT: {rep['n'] - rep['ok']} of {rep['n']} "
-                  "requests did not succeed", file=sys.stderr)
+        problems = []
+        if rep["ok"] != rep["n"]:
+            problems.append(f"{rep['n'] - rep['ok']} of {rep['n']} "
+                            "requests did not succeed")
+        if s.degraded:
+            problems.append(f"service degraded: {s.degraded_reason}")
+        if service.oracle.bank_error:
+            problems.append(f"bank build failed: {service.oracle.bank_error}")
+        if (local_workers > 0 or remote) and plane is None:
+            problems.append("shard plane unavailable")
+        if args.strict and problems:
+            print("STRICT: " + "; ".join(problems), file=sys.stderr)
             return 1
         return 0
     finally:
@@ -231,4 +242,6 @@ def main(argv=None):
 
 
 if __name__ == "__main__":
+    from repro import compile_cache
+    compile_cache.enable()
     sys.exit(main())

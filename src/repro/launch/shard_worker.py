@@ -11,7 +11,9 @@ pick) as its first stdout line so launchers can parse where to connect::
 Point a serving parent at it with ``serve_http --remote-worker
 HOST:7421`` (or ``ShardPlane(remote=["HOST:7421"])``). The worker holds
 no durable state — banks arrive per generation over the wire and die
-with the connection — so restarting one is always safe.
+with the connection — so restarting one is always safe. It is CPU-only by
+construction (``JAX_PLATFORMS=cpu``, numpy forest backend): on a chip
+host the accelerator stays with the serving parent.
 """
 from __future__ import annotations
 
@@ -23,7 +25,7 @@ import threading
 from typing import Optional, Sequence
 
 from repro.serve import frames
-from repro.serve.shard import WorkerServer
+from repro.serve.shard import WorkerServer, _cpu_only
 
 
 def main(argv: Optional[Sequence[str]] = None) -> int:
@@ -41,6 +43,7 @@ def main(argv: Optional[Sequence[str]] = None) -> int:
                          "closed before any load is processed (defaults "
                          "to $PROFET_WORKER_TOKEN; empty = no auth)")
     args = ap.parse_args(argv)
+    _cpu_only()
     token = args.token if args.token is not None \
         else os.environ.get("PROFET_WORKER_TOKEN")
     if not token:                 # empty string disables auth too

@@ -59,13 +59,12 @@ def _batch_shard_map(fn, mesh, n_in):
     replicated elsewhere. A shard_map region is OPAQUE to the SPMD
     partitioner, so the data-dependent scatter/gather inside executes
     locally per batch shard — no partitioner fallback possible."""
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     batch = tuple(a for a in ("pod", "data") if a in mesh.axis_names)
     spec = P(batch)
-    return shard_map(fn, mesh=mesh, in_specs=(spec,) * n_in, out_specs=spec,
-                     check_rep=False)
+    return jax.shard_map(fn, mesh=mesh, in_specs=(spec,) * n_in,
+                         out_specs=spec, check_vma=False)
 
 
 def _make_dispatch_combine(E, capacity):

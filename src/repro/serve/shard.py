@@ -15,8 +15,11 @@ Three worker kinds share one protocol:
     (forest node tensors + linear coefficients) are published once per
     generation through ``multiprocessing.shared_memory`` and mapped
     read-only by every worker — a load ships names and shapes, not
-    gigabytes. Workers never import jax unless the bank carries a DNN
-    member (the spec resolves the forest backend parent-side).
+    gigabytes. Workers are CPU-only by construction: every worker
+    process runs with ``JAX_PLATFORMS=cpu`` and the numpy forest backend,
+    so the one accelerator of a chip host stays with the serving parent
+    (a chip belongs to one process). Workers import jax only when the
+    bank carries a DNN member.
   - ``mode="thread"`` — in-process workers sharing sub-banks by
     reference. Deterministic and cheap: the test suite drives shuffled
     completion orders, mid-wave deaths, and swap races through its
@@ -105,6 +108,14 @@ class WorkerAuthError(RuntimeError):
 # ----------------------------------------------------------------------
 # bank <-> worker spec (spawn mode)
 # ----------------------------------------------------------------------
+def _cpu_only() -> None:
+    """Pin this worker process's jax (if it ever loads) to the CPU, so it
+    can never try to open the accelerator the serving parent holds."""
+    os.environ["JAX_PLATFORMS"] = "cpu"
+    if "jax" in sys.modules:            # imported, but no backend yet
+        sys.modules["jax"].config.update("jax_platforms", "cpu")
+
+
 def _bank_to_spec(bank: ModelBank) -> Tuple[dict, list]:
     """Publish ``bank``'s big stacked arrays as shared-memory segments
     and return ``(spec, segments)``: a small picklable spec (names +
@@ -131,19 +142,13 @@ def _bank_to_spec(bank: ModelBank) -> Tuple[dict, list]:
     except Exception:
         _release_segments(segments, unlink=True)
         raise
-    backend = bank.backend
-    if backend == "auto" and "forest" in bank.members:
-        # resolve here, where jax is already warm: CPU workers then serve
-        # the numpy traversal without ever importing jax
-        from repro.kernels import forest_eval
-        backend = forest_eval._auto_backend()
     spec = {
         "pairs": bank.pairs,
         "members": bank.members,
         "n_features": bank.n_features,
         "devices": bank.devices,
         "scalers": bank.scalers,
-        "backend": backend,
+        "backend": "numpy",             # what a CPU-only worker runs
         "depth": (None if bank.forest is None
                   else np.asarray(bank.forest["depth"])),
         "dnn": (None if bank.dnn is None
@@ -198,6 +203,7 @@ def _spawn_worker_main(conn) -> None:
     """Spawn-worker child loop (module level: spawn pickles the target).
     One request, one reply, strictly in order — the parent's dispatcher
     thread is the only writer on the other end."""
+    _cpu_only()
     banks: Dict[int, Tuple[ModelBank, list]] = {}
     while True:
         try:
@@ -897,6 +903,7 @@ def launch_tcp_workers(n: int, *, host: str = "127.0.0.1",
     src = os.path.dirname(os.path.abspath(list(repro.__path__)[0]))
     env["PYTHONPATH"] = src + (
         os.pathsep + env["PYTHONPATH"] if env.get("PYTHONPATH") else "")
+    env["JAX_PLATFORMS"] = "cpu"        # the chip stays with the parent
     if token is not None:
         env["PROFET_WORKER_TOKEN"] = token
     else:

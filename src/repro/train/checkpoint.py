@@ -31,9 +31,7 @@ _BITCAST = {"bfloat16": np.uint16, "float8_e4m3fn": np.uint8,
 
 
 def _flatten_with_paths(tree) -> Tuple[List[Tuple[str, Any]], Any]:
-    # jax.tree.flatten_with_path only exists from jax 0.4.38 on; the
-    # tree_util spelling works on every version this repo supports
-    flat, treedef = jax.tree_util.tree_flatten_with_path(tree)
+    flat, treedef = jax.tree.flatten_with_path(tree)
     out = []
     for path, leaf in flat:
         out.append((jax.tree_util.keystr(path), leaf))

@@ -171,13 +171,13 @@ def test_bank_pads_ragged_forests(oracle):
 # ---------------------------------------------------------------------------
 
 
-def _toy_forest_stack(seed=0, n_groups=3):
+def _toy_forest_stack(seed=0, n_groups=3, n_trees=8, d=4):
     rng = np.random.default_rng(seed)
     forests = []
     for g in range(n_groups):
-        X = rng.uniform(-2, 2, size=(50 + 30 * g, 4))
-        y = np.sin(X[:, 0] * (g + 1)) + X[:, 1]
-        rf = RandomForestRegressor(n_estimators=8, max_depth=5 + g,
+        X = rng.uniform(-2, 2, size=(50 + 30 * g, d))
+        y = np.sin(X[:, 0] * (g + 1)) + X[:, d - 1]
+        rf = RandomForestRegressor(n_estimators=n_trees, max_depth=5 + g,
                                    seed=g).fit(X, y)
         forests.append(rf.forest_)
     T = forests[0].n_trees
@@ -209,21 +209,30 @@ def test_grouped_numpy_matches_per_group_kernel():
         np.testing.assert_array_equal(got[:, sel], ref)
 
 
-def test_grouped_pallas_interpret_matches_grouped_numpy():
-    """The (group, row-block) Pallas kernel (interpret mode) agrees exactly
-    with the grouped numpy traversal on a float32-quantized bank."""
-    _, s = _toy_forest_stack(seed=3)
+@pytest.mark.parametrize("n_trees,d", [(8, 4), (11, 13)],
+                         ids=["aligned-trees", "padded-trees-features"])
+def test_grouped_pallas_interpret_matches_grouped_numpy(n_trees, d):
+    """The (row-block, tree-tile) Pallas kernel (interpret mode) agrees
+    exactly with the grouped numpy traversal on a float32-quantized bank,
+    including the kernel's padding: node counts that are not a multiple
+    of 128, tree counts that are not a multiple of 8, feature counts that
+    are not a multiple of 8, and rows spilling over one row block."""
+    _, s = _toy_forest_stack(seed=3, n_trees=n_trees, d=d)
+    G, T, N = s["feat"].shape
+    assert T == n_trees and N % forest_eval.LANES
+    n = forest_eval.LANES + 37
     rng = np.random.default_rng(11)
-    X = rng.uniform(-2, 2, size=(37, 4)).astype(np.float32).astype(
+    X = rng.uniform(-2, 2, size=(n, d)).astype(np.float32).astype(
         np.float64)
     thr32 = s["thr"].astype(np.float32).astype(np.float64)
-    gid = rng.integers(0, s["feat"].shape[0], size=37)
+    gid = rng.integers(0, G, size=n)
+    gid[:forest_eval.LANES + 5] = 1          # group 1 fills two row blocks
     v_np = forest_eval.leaf_values_grouped_numpy(
         X, gid, s["feat"], thr32, s["left"], s["right"], s["value"],
         s["depth"])
     v_pl = forest_eval.leaf_values_grouped_pallas(
         X, gid, s["feat"], thr32, s["left"], s["right"], s["value"],
-        depth=s["depth"], block_rows=8, interpret=True)
+        depth=s["depth"])
     np.testing.assert_array_equal(v_np.astype(np.float32), v_pl)
 
 

@@ -231,6 +231,37 @@ def test_warmup_failure_degrades_then_healthy_swap_recovers(oracle, oracle2):
                                rtol=1e-12)
 
 
+@pytest.mark.parametrize("broken,says", [
+    ("warmup", "service degraded"),
+    ("bank", "bank build failed"),
+    ("plane", "shard plane unavailable")])
+def test_serve_http_strict_fails_when_degraded(monkeypatch, capsys, broken,
+                                               says):
+    """``serve_http --strict`` gates on the device path, not only on
+    answers: a degraded boot (warm-up failure), a failed bank build or a
+    requested shard plane that never came up answers every replay request
+    and still exits non-zero."""
+    import repro.serve
+    from repro.api.bank import ModelBank
+    from repro.launch import serve_http
+
+    def boom(*args, **kwargs):
+        raise RuntimeError(f"injected {broken} failure")
+
+    argv = ["--requests", "12", "--clients", "2", "--strict"]
+    if broken == "warmup":
+        monkeypatch.setattr(api.LatencyOracle, "warmup", boom)
+    elif broken == "bank":
+        monkeypatch.setattr(ModelBank, "build", classmethod(boom))
+    else:
+        monkeypatch.setattr(repro.serve, "ShardPlane", boom)
+        argv += ["--workers", "1"]
+    rc = serve_http.main(argv)
+    out = capsys.readouterr()
+    assert "replay: 12/12 ok" in out.out
+    assert rc == 1 and "STRICT" in out.err and says in out.err
+
+
 def test_circuit_breaker_quarantines_and_half_open_probe_recovers(oracle):
     clk = [0.0]
     breaker = CircuitBreaker(threshold=2, cooldown_s=10.0,

@@ -81,6 +81,13 @@ def test_grower_feature_subsampling_stays_deterministic():
 # ---------------------------------------------------------------------------
 
 
+def _one_group(X, f, thr, value):
+    """The grouped Pallas kernel over a single forest (G=1)."""
+    return forest_eval.leaf_values_grouped_pallas(
+        X, np.zeros(len(X), np.int64), f.feat[None], thr[None],
+        f.left[None], f.right[None], value[None], depth=[f.depth])
+
+
 def test_forest_eval_pallas_matches_numpy_exactly():
     X, y, Xq = _forest_data(n=120, d=4, seed=7)
     f = RandomForestRegressor(n_estimators=9, seed=2).fit(X, y).forest_
@@ -91,23 +98,24 @@ def test_forest_eval_pallas_matches_numpy_exactly():
     val32 = f.value.astype(np.float32)
     v_np = forest_eval.leaf_values_numpy(X32, f.feat, thr32, f.left,
                                          f.right, val32)
-    v_pl = forest_eval.leaf_values_pallas(X32, f.feat, thr32, f.left,
-                                          f.right, val32, depth=f.depth)
+    v_pl = _one_group(X32, f, thr32, val32)
     np.testing.assert_array_equal(v_np.astype(np.float32), v_pl)
 
 
 def test_forest_eval_pallas_blocking_covers_ragged_rows():
+    """Rows spanning several row blocks, the last one ragged, answer as
+    they do alone: routing never mixes rows across block padding."""
     X, y, _ = _forest_data(n=80, d=3, seed=11)
     f = RandomForestRegressor(n_estimators=4, seed=4).fit(X, y).forest_
-    Xq = np.random.default_rng(0).normal(size=(13, 3)).astype(np.float32)
-    v_full = forest_eval.leaf_values_pallas(
-        Xq, f.feat, f.thr.astype(np.float32), f.left, f.right,
-        f.value.astype(np.float32), depth=f.depth, block_rows=256)
-    v_blocked = forest_eval.leaf_values_pallas(
-        Xq, f.feat, f.thr.astype(np.float32), f.left, f.right,
-        f.value.astype(np.float32), depth=f.depth, block_rows=4)
+    n = 2 * forest_eval.LANES + 44
+    Xq = np.random.default_rng(0).normal(size=(n, 3)).astype(np.float32)
+    thr32, val32 = f.thr.astype(np.float32), f.value.astype(np.float32)
+    v_full = _one_group(Xq, f, thr32, val32)
+    v_blocked = np.concatenate([_one_group(Xq[:13], f, thr32, val32),
+                                _one_group(Xq[13:], f, thr32, val32)],
+                               axis=1)
     np.testing.assert_array_equal(v_full, v_blocked)
-    assert v_full.shape == (4, 13)
+    assert v_full.shape == (4, n)
 
 
 def test_forest_predict_backends_agree_and_rejects_unknown():

@@ -39,7 +39,8 @@ def seq(x):
     return out
 ref = seq(x)
 
-mesh = jax.make_mesh((4,), ("pod",))
+mesh = jax.make_mesh((4,), ("pod",),
+                     axis_types=(jax.sharding.AxisType.Auto,))
 out = jax.jit(lambda p, x: pipeline_apply(
     block, p, x, mesh=mesh, axis="pod", microbatches=M))(params, x)
 err = float(jnp.abs(out - ref).max())

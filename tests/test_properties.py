@@ -82,11 +82,10 @@ sys.path.insert(0, "src")
 import json
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
-from jax.sharding import PartitionSpec as P
+from jax.sharding import AxisType, PartitionSpec as P
 from repro.distributed import compression as COMP
 
-mesh = jax.make_mesh((4,), ("pod",))
+mesh = jax.make_mesh((4,), ("pod",), axis_types=(AxisType.Auto,))
 # per-pod distinct gradients: mean must come out right through int8
 g = jnp.stack([jnp.linspace(-1, 1, 64) * (i + 1) for i in range(4)])
 r = jnp.zeros((4, 64))
@@ -95,8 +94,8 @@ def f(g, r):
     out, new_r = COMP.compressed_psum({"w": g[0]}, {"w": r[0]}, "pod")
     return out["w"][None], new_r["w"][None]
 
-out, _ = shard_map(f, mesh=mesh, in_specs=(P("pod"), P("pod")),
-                   out_specs=(P("pod"), P("pod")))(g, r)
+out, _ = jax.shard_map(f, mesh=mesh, in_specs=(P("pod"), P("pod")),
+                       out_specs=(P("pod"), P("pod")))(g, r)
 true_mean = g.mean(0)
 err = float(jnp.abs(out[0] - true_mean).max())
 print(json.dumps({"err": err, "devices": jax.device_count()}))

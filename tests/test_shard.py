@@ -106,6 +106,44 @@ def test_bank_split_empty_and_unknown(oracle):
 
 
 # ---------------------------------------------------------------------------
+# workers are CPU-only: the chip stays with the serving parent
+# ---------------------------------------------------------------------------
+
+
+@pytest.mark.parametrize("ship", ["spawn-spec", "tcp-payload"])
+def test_worker_bank_resolves_to_numpy_backend(oracle, ship):
+    """A bank the parent runs on the Pallas kernel reaches every worker
+    kind as the numpy traversal, the backend a CPU-only worker runs."""
+    from repro.api.bank import ModelBank
+    from repro.serve import shard
+    bank = ModelBank.build(oracle.profet, backend="pallas")
+    if ship == "spawn-spec":
+        spec, segments = shard._bank_to_spec(bank)
+        shard._release_segments(segments, unlink=True)
+        backend = spec["backend"]
+    else:
+        backend = bank.to_payload()["backend"]
+    assert backend == "numpy"
+
+
+def test_tcp_worker_launch_pins_jax_to_cpu(monkeypatch):
+    from repro.serve import shard
+    seen = {}
+
+    class Launched(RuntimeError):
+        pass
+
+    def fake_popen(cmd, **kw):
+        seen.update(kw["env"])
+        raise Launched()
+
+    monkeypatch.setattr(shard.subprocess, "Popen", fake_popen)
+    with pytest.raises(Launched):
+        launch_tcp_workers(1)
+    assert seen["JAX_PLATFORMS"] == "cpu"
+
+
+# ---------------------------------------------------------------------------
 # scatter/gather
 # ---------------------------------------------------------------------------
 

@@ -218,7 +218,8 @@ def test_elastic_restore_across_meshes(tmp_path, smoke_cfg):
                      ckpt_every=2, ckpt_dir=str(tmp_path))
     tr = Trainer(smoke_cfg, tc)
     tr.run()
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = jax.make_mesh((1, 1), ("data", "model"),
+                         axis_types=(jax.sharding.AxisType.Auto,) * 2)
     from repro.distributed import sharding as SH
     p_sh = SH.tree_param_shardings(tr.axes, mesh, tr.params)
     step, out = CKPT.restore_latest(
@@ -256,18 +257,17 @@ def test_error_feedback_is_unbiased_over_steps():
 
 
 def test_compressed_psum_single_axis():
-    from jax.experimental.shard_map import shard_map
-    from jax.sharding import PartitionSpec as P
+    from jax.sharding import AxisType, PartitionSpec as P
 
-    mesh = jax.make_mesh((1,), ("pod",))
+    mesh = jax.make_mesh((1,), ("pod",), axis_types=(AxisType.Auto,))
     g = {"w": jnp.linspace(-1, 1, 16).reshape(4, 4)}
     r = COMP.init_residuals(g)
 
     def f(g, r):
         return COMP.compressed_psum(g, r, "pod")
 
-    out, new_r = shard_map(f, mesh=mesh, in_specs=(P(), P()),
-                           out_specs=(P(), P()))(g, r)
+    out, new_r = jax.shard_map(f, mesh=mesh, in_specs=(P(), P()),
+                               out_specs=(P(), P()))(g, r)
     np.testing.assert_allclose(out["w"], g["w"], atol=2e-2)
 
 
