@@ -1,0 +1,19 @@
+"""encode_ms.point: mean time per response to encode its JSON, build its
+head and hand both to the socket, not counting the drain
+(``transport.encode`` in /statsz ``trace``, over the window)."""
+
+
+def read(ctx):
+    d = _delta(ctx, "transport.encode")
+    return None if d is None else 1e3 * d["total_s"] / d["n"]
+
+
+def _delta(ctx, name):
+    """``name``'s row of /statsz ``trace`` over the window; None where the
+    program keeps no such row or it did not move."""
+    b, a = ctx.statsz_before.get("trace"), ctx.statsz_after.get("trace")
+    if b is None or a is None or name not in a["spans"]:
+        return None
+    was = b["spans"].get(name, {"n": 0, "total_s": 0.0, "self_s": 0.0})
+    d = {k: a["spans"][name][k] - was[k] for k in ("n", "total_s", "self_s")}
+    return d if d["n"] > 0 else None

@@ -44,6 +44,7 @@ from typing import Dict, Iterable, Optional, Sequence, Tuple
 
 import numpy as np
 
+from repro import obs
 from repro.core.regressors import (DNNRegressor, LinearRegressor,
                                    RandomForestRegressor, _mlp_apply_multi,
                                    bucket, stack_dnn_heads)
@@ -320,9 +321,10 @@ class ModelBank:
         if "forest" in self.members:
             from repro.kernels import forest_eval
             f = self.forest
-            preds.append(forest_eval.predict_grouped(
-                X, gids, f["feat"], f["thr"], f["left"], f["right"],
-                f["value"], depth=f["depth"], backend=self.backend))
+            with obs.span("bank.forest", rows=len(gids)):
+                preds.append(forest_eval.predict_grouped(
+                    X, gids, f["feat"], f["thr"], f["left"], f["right"],
+                    f["value"], depth=f["depth"], backend=self.backend))
             self.forest_launches += 1
         if "dnn" in self.members:
             preds.append(self._dnn_member(X, gids))
@@ -332,27 +334,30 @@ class ModelBank:
         """One stacked MLP apply: rows scattered into a dense bucketed
         ``(groups, rows, features)`` block, heads gathered on device."""
         import jax.numpy as jnp
-        params, mu, sd, ys = self.dnn
-        uniq, local = np.unique(gids, return_inverse=True)
-        counts = np.bincount(local)
-        g_pad = bucket(len(uniq))
-        r_pad = bucket(int(counts.max()), DNNRegressor.PREDICT_BUCKET_MIN)
-        # per-row slot inside its group's row block
-        order = np.argsort(local, kind="stable")
-        starts = np.concatenate([[0], np.cumsum(counts)[:-1]])
-        slot = np.empty(len(gids), np.int64)
-        slot[order] = np.arange(len(gids)) - starts[local[order]]
-        # normalized exactly like DNNRegressor.predict: float64 z-score,
-        # then one float32 cast
-        Xn = ((X - mu[gids]) / sd[gids]).astype(np.float32)
-        block = np.zeros((g_pad, r_pad, X.shape[1]), np.float32)
-        block[local, slot] = Xn
-        gidx = np.zeros(g_pad, np.int32)
-        gidx[:len(uniq)] = uniq
-        out = np.asarray(_mlp_apply_multi()(params, jnp.asarray(gidx),
-                                            jnp.asarray(block)))
-        self.mlp_applies += 1
-        return out[local, slot] * ys[gids]
+        with obs.span("bank.mlp", rows=len(gids)):
+            params, mu, sd, ys = self.dnn
+            uniq, local = np.unique(gids, return_inverse=True)
+            counts = np.bincount(local)
+            g_pad = bucket(len(uniq))
+            r_pad = bucket(int(counts.max()), DNNRegressor.PREDICT_BUCKET_MIN)
+            # per-row slot inside its group's row block
+            order = np.argsort(local, kind="stable")
+            starts = np.concatenate([[0], np.cumsum(counts)[:-1]])
+            slot = np.empty(len(gids), np.int64)
+            slot[order] = np.arange(len(gids)) - starts[local[order]]
+            # normalized exactly like DNNRegressor.predict: float64 z-score,
+            # then one float32 cast
+            Xn = ((X - mu[gids]) / sd[gids]).astype(np.float32)
+            block = np.zeros((g_pad, r_pad, X.shape[1]), np.float32)
+            block[local, slot] = Xn
+            gidx = np.zeros(g_pad, np.int32)
+            gidx[:len(uniq)] = uniq
+            # the params live on the device; the index and the block cross
+            obs.count("bank.h2d_bytes", gidx.nbytes + block.nbytes)
+            out = np.asarray(_mlp_apply_multi()(params, jnp.asarray(gidx),
+                                                jnp.asarray(block)))
+            self.mlp_applies += 1
+            return out[local, slot] * ys[gids]
 
     def interpolate(self, kinds: Sequence[str], dev_ids: np.ndarray,
                     values: np.ndarray, t_min: np.ndarray,
@@ -360,24 +365,25 @@ class ModelBank:
         """Vectorized phase-2 over heterogeneous rows: one Horner pass,
         each row using its (device, knob-kind) coefficient row — bitwise
         equal to per-group ``PolyScaler.predict``."""
-        n = len(values)
-        coef = np.empty((n, self.scalers["batch"][0].shape[1]))
-        lo = np.empty(n)
-        hi = np.empty(n)
-        for kind in ("batch", "pixel"):
-            sel = np.array([k == kind for k in kinds])
-            if not sel.any():
-                continue
-            c, l, h = self.scalers[kind]
-            coef[sel] = c[dev_ids[sel]]
-            lo[sel] = l[dev_ids[sel]]
-            hi[sel] = h[dev_ids[sel]]
-        x = (np.asarray(values, np.float64) - lo) / (hi - lo)
-        r = np.zeros(n)
-        for j in range(coef.shape[1]):
-            r = r * x + coef[:, j]
-        return r * (np.asarray(t_max) - np.asarray(t_min)) + \
-            np.asarray(t_min)
+        with obs.span("bank.phase2", rows=len(values)):
+            n = len(values)
+            coef = np.empty((n, self.scalers["batch"][0].shape[1]))
+            lo = np.empty(n)
+            hi = np.empty(n)
+            for kind in ("batch", "pixel"):
+                sel = np.array([k == kind for k in kinds])
+                if not sel.any():
+                    continue
+                c, l, h = self.scalers[kind]
+                coef[sel] = c[dev_ids[sel]]
+                lo[sel] = l[dev_ids[sel]]
+                hi[sel] = h[dev_ids[sel]]
+            x = (np.asarray(values, np.float64) - lo) / (hi - lo)
+            r = np.zeros(n)
+            for j in range(coef.shape[1]):
+                r = r * x + coef[:, j]
+            return r * (np.asarray(t_max) - np.asarray(t_min)) + \
+                np.asarray(t_min)
 
     # ------------------------------------------------------------------
     # warm-up

@@ -35,6 +35,7 @@ from typing import Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
 
+from repro import obs
 from repro.core import workloads
 from repro.core.predictor import Profet, ProfetConfig
 from repro.api import planner as planner_mod
@@ -244,10 +245,11 @@ class LatencyOracle:
         slicing of the same tensors."""
         if bank is None:
             bank = self.bank if banked else None
-        return execute_plans(self.profet, plans,
-                             epoch=self.fingerprint if epoch is None
-                             else epoch,
-                             bank=bank)
+        with obs.span("executor.execute", n=len(plans)):
+            return execute_plans(self.profet, plans,
+                                 epoch=self.fingerprint if epoch is None
+                                 else epoch,
+                                 bank=bank)
 
     def predict_many(self,
                      reqs: Sequence[PredictRequest]) -> BatchPredictResult:

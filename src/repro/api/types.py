@@ -14,6 +14,8 @@ from typing import Dict, Iterator, Mapping, Optional, Tuple
 
 import numpy as np
 
+from repro import obs
+
 # Request modes (``PredictRequest.mode``)
 MODE_AUTO = "auto"            # cross if an exact-case profile exists, else two-phase
 MODE_CROSS = "cross"          # phase-1 only: profile of the exact case required
@@ -276,7 +278,10 @@ class ServiceStats:
     ``pump_crashes``/``pump_restarts`` account the transport pump
     supervisor; ``degraded`` (+ ``degraded_reason``) is set while the
     service runs a fallback path (e.g. per-group execute after a
-    warm-up/bank failure) and clears when a healthy oracle is swapped in."""
+    warm-up/bank failure) and clears when a healthy oracle is swapped in.
+
+    :meth:`summary` also carries ``trace``: the process-wide span and
+    counter totals of :mod:`repro.obs`."""
     requests: int = 0
     waves: int = 0
     fused_calls: int = 0
@@ -341,7 +346,8 @@ class ServiceStats:
                 "degraded": self.degraded,
                 "degraded_reason": self.degraded_reason,
                 "p50_ms": self.p50_ms, "p99_ms": self.p99_ms,
-                "requests_per_s": self.requests_per_s}
+                "requests_per_s": self.requests_per_s,
+                "trace": obs.snapshot()}
 
 
 @dataclasses.dataclass(frozen=True)

@@ -586,13 +586,14 @@ def _mlp_apply_multi():
         return _APPLY_MULTI_FN
     import jax
 
+    # named so the trace's module reads ``jit_bank_mlp``
     @jax.jit
-    def apply(params, gidx, Xn):
+    def bank_mlp(params, gidx, Xn):
         picked = jax.tree.map(lambda a: a[gidx], params)
         return jax.vmap(_mlp_apply)(picked, Xn)      # (Gb, Rb)
 
-    _APPLY_MULTI_FN = apply
-    return apply
+    _APPLY_MULTI_FN = bank_mlp
+    return bank_mlp
 
 
 def stack_dnn_heads(models: List["DNNRegressor"]):

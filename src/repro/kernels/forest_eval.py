@@ -31,6 +31,8 @@ from typing import Optional
 
 import numpy as np
 
+from repro import obs
+
 
 def tree_mean(vals: np.ndarray) -> np.ndarray:
     """Float64 mean over the tree axis of ``(n_trees, n_rows)`` leaf values,
@@ -306,9 +308,14 @@ def leaf_values_grouped_pallas(X, gid, feat, thr, left, right, value, *,
     xt = np.zeros((_round_up(d, SUBLANES), n_blocks * LANES), np.float32)
     xt[:d, pos] = X[order].T
 
-    out = np.asarray(_grouped_fn()(
-        block_gid, block_depth, xt,
-        *pad_forest_stack(feat, thr, left, right, value)))
+    args = (block_gid, block_depth, xt,
+            *pad_forest_stack(feat, thr, left, right, value))
+    # host arrays cross to the device on every launch; device arrays do not
+    obs.count("bank.h2d_bytes", sum(a.nbytes for a in args
+                                    if isinstance(a, np.ndarray)))
+    obs.count("bank.forest_rows", m)
+    obs.count("bank.forest_slots", n_blocks * LANES)
+    out = np.asarray(_grouped_fn()(*args))
     res = np.empty((T, m), np.float32)
     res[:, order] = out[:T, pos]
     return res

@@ -41,6 +41,7 @@ thread drains waves.
 from __future__ import annotations
 
 import dataclasses
+import itertools
 import threading
 import time
 from collections import OrderedDict
@@ -48,6 +49,7 @@ from typing import List, Optional, Sequence, Tuple
 
 import numpy as np
 
+from repro import obs
 from repro.api.oracle import LatencyOracle
 from repro.api.planner import minmax_cases, request_fingerprint
 from repro.api.types import (ANCHOR_ANY, ApiError, CircuitOpenError,
@@ -100,6 +102,7 @@ class LatencyService:
         self.stats = ServiceStats()
         self._cache: "OrderedDict[tuple, PredictResult]" = OrderedDict()
         self._uid = 0
+        self._wave_seq = itertools.count()     # names waves in the trace
         self._lock = threading.Lock()
         self._epoch = epoch if epoch is not None else oracle.fingerprint
         # insertion-ordered bounded memory of every epoch label served
@@ -368,7 +371,8 @@ class LatencyService:
                 continue
             try:
                 faults_mod.fire(self._faults, faults_mod.SITE_PLAN)
-                plan = oracle.plan(sr.request)
+                with obs.span("planner.plan", uid=sr.uid):
+                    plan = oracle.plan(sr.request)
             except ApiError as e:
                 self._fail(sr, e)
                 continue
@@ -463,7 +467,11 @@ class LatencyService:
             sharded = self._shard_gen if (wave and self._banked) else None
             if sharded is not None:
                 self.shard_plane.acquire(sharded)
-            return wave, self.oracle, self._epoch, sharded
+            oracle, epoch = self.oracle, self._epoch
+        now = time.perf_counter()
+        for sr in wave:
+            obs.record("latency_service.queue_wait", now - sr.t_submit)
+        return wave, oracle, epoch, sharded
 
     def run_once(self) -> int:
         """Admit and execute ONE wave; returns how many requests it
@@ -475,7 +483,9 @@ class LatencyService:
         if not wave:
             return 0
         try:
-            self._run_wave(wave, oracle, epoch, sharded)
+            with obs.span("latency_service.wave",
+                          wave=next(self._wave_seq), n=len(wave)):
+                self._run_wave(wave, oracle, epoch, sharded)
         finally:
             if sharded is not None:
                 self.shard_plane.release(sharded)

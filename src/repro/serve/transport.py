@@ -60,6 +60,7 @@ from typing import Any, Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
 
+from repro import obs
 from repro.api import oracle as oracle_mod
 from repro.api.types import (ApiError, ExecutionError, GridRequest,
                              KNOB_BATCH, KNOB_PIXEL, MODE_AUTO,
@@ -135,6 +136,31 @@ def grid_request_from_dict(d: Any) -> GridRequest:
                            pixels=tuple(int(p) for p in d["pixels"]))
     except (KeyError, TypeError, ValueError) as e:
         raise MalformedRequestError(f"bad grid payload: {e!r}") from e
+
+
+def advise_args_from_dict(d: Any) -> tuple:
+    """``(anchor, workload, profile, measured_ms, targets)`` of an
+    ``/advise`` payload, the arguments of ``LatencyOracle.stage_advise``."""
+    if not isinstance(d, dict):
+        raise MalformedRequestError(
+            f"advise payload must be a JSON object, got {type(d).__name__}")
+    try:
+        anchor = str(d["anchor"])
+        w = d["workload"]
+        workload = Workload(model=str(w["model"]), batch=int(w["batch"]),
+                            pix=int(w["pix"]))
+        profile = d.get("profile")
+        if profile is not None:
+            profile = {str(k): float(v) for k, v in profile.items()}
+        measured = d.get("measured_ms")
+        measured = None if measured is None else float(measured)
+        targets = d.get("targets")
+        targets = None if targets is None else [str(t) for t in targets]
+        return anchor, workload, profile, measured, targets
+    except ApiError:
+        raise
+    except (KeyError, TypeError, ValueError, AttributeError) as e:
+        raise MalformedRequestError(f"bad advise payload: {e!r}") from e
 
 
 def _error_payload(e: Exception) -> Tuple[int, Dict[str, Any]]:
@@ -217,8 +243,11 @@ class TransportServer:
     # ------------------------------------------------------------------
     # admission + wave pump
     # ------------------------------------------------------------------
-    def _admit(self, reqs: Sequence[PredictRequest]) -> List[asyncio.Future]:
-        """Bounded admission: all-or-nothing enqueue of a request group."""
+    def _admit(self, reqs: Sequence[PredictRequest],
+               decode: obs.span) -> List[asyncio.Future]:
+        """Bounded admission: all-or-nothing enqueue of a request group.
+        Tags the group's open ``decode`` span with its first uid and its
+        size, so the trace joins the decode to the requests' later spans."""
         if len(self._futs) + len(reqs) > self.max_queue:
             self.service.stats.overloads += 1
             raise OverloadedError(
@@ -230,6 +259,8 @@ class TransportServer:
             fut = self._loop.create_future()
             self._futs[sr.uid] = fut
             futs.append(fut)
+            if len(futs) == 1:
+                decode.set(uid=sr.uid, n=len(reqs))
         self._wake.set()
         return futs
 
@@ -270,6 +301,10 @@ class TransportServer:
             fut = self._futs.pop(sr.uid, None)
             if fut is not None and not fut.done():
                 fut.set_result(sr)
+                # completion to resolution: a request finished early in its
+                # wave (a cache hit) waits here for the rest of the wave
+                obs.record("transport.resolve",
+                           time.perf_counter() - sr.t_finish)
 
     async def _pump(self) -> None:
         while True:
@@ -379,30 +414,33 @@ class TransportServer:
                 status, payload = ready
             if closing:
                 continue
-            data = json.dumps(payload).encode()
-            head = (b"HTTP/1.1 %d %s\r\n"
-                    b"Content-Type: application/json\r\n"
-                    b"Content-Length: %d\r\n"
-                    b"X-Profet-Protocol: %s\r\n"
-                    b"Connection: %s\r\n\r\n"
-                    % (status, _reason(status).encode(), len(data),
-                       PROTOCOL.encode(),
-                       b"keep-alive" if keep else b"close"))
+            # injected socket reset mid-response: the request WAS
+            # executed, but the client sees a truncated response and a
+            # dead connection — the retry-safety scenario. Closing below
+            # also EOFs the read loop.
+            drop = faults_mod.should_drop(self._faults,
+                                          faults_mod.SITE_RESPONSE)
             try:
-                if faults_mod.should_drop(self._faults,
-                                          faults_mod.SITE_RESPONSE):
-                    # injected socket reset mid-response: the request WAS
-                    # executed, but the client sees a truncated response
-                    # and a dead connection — the retry-safety scenario.
-                    # Closing here also EOFs the read loop.
-                    writer.write(head + data[:max(1, len(data) // 2)])
-                    await writer.drain()
+                with obs.span("transport.encode", status=status):
+                    data = json.dumps(payload).encode()
+                    head = (b"HTTP/1.1 %d %s\r\n"
+                            b"Content-Type: application/json\r\n"
+                            b"Content-Length: %d\r\n"
+                            b"X-Profet-Protocol: %s\r\n"
+                            b"Connection: %s\r\n\r\n"
+                            % (status, _reason(status).encode(), len(data),
+                               PROTOCOL.encode(),
+                               b"keep-alive" if keep else b"close"))
+                    if drop:
+                        writer.write(head + data[:max(1, len(data) // 2)])
+                    else:
+                        writer.write(head)
+                        writer.write(data)
+                await writer.drain()
+                if drop:
                     writer.close()
                     closing = True
                     continue
-                writer.write(head)
-                writer.write(data)
-                await writer.drain()
             except (ConnectionError, OSError):
                 closing = True
                 continue
@@ -519,15 +557,15 @@ class TransportServer:
             if path == "/predict":
                 if method != "POST":
                     return 405, _method_not_allowed(method)
-                return await self._predict(_decode_json(body), deadline)
+                return await self._predict(body, deadline)
             if path == "/grid":
                 if method != "POST":
                     return 405, _method_not_allowed(method)
-                return await self._grid(_decode_json(body), deadline)
+                return await self._grid(body, deadline)
             if path == "/advise":
                 if method != "POST":
                     return 405, _method_not_allowed(method)
-                return await self._advise(_decode_json(body), deadline)
+                return await self._advise(body, deadline)
             if path == "/measure":
                 if method != "POST":
                     return 405, _method_not_allowed(method)
@@ -541,12 +579,15 @@ class TransportServer:
     # ------------------------------------------------------------------
     # endpoints
     # ------------------------------------------------------------------
-    async def _predict(self, payload: Any,
+    # Each endpoint decodes and admits its request inside one
+    # ``transport.decode`` span, then awaits outside it.
+    async def _predict(self, body: bytes,
                        deadline_ms: Optional[float] = None
                        ) -> Tuple[int, Dict[str, Any]]:
-        req = _with_deadline(predict_request_from_dict(payload),
-                             deadline_ms)
-        [fut] = self._admit([req])
+        with obs.span("transport.decode") as sp:
+            req = _with_deadline(
+                predict_request_from_dict(_decode_json(body)), deadline_ms)
+            [fut] = self._admit([req], sp)
         sr = await fut
         if sr.error is not None:
             status, out = _error_payload(sr.error)
@@ -564,15 +605,18 @@ class TransportServer:
                 f"admission queue holds ({self.max_queue}); split the "
                 "sweep")
 
-    async def _grid(self, payload: Any,
+    async def _grid(self, body: bytes,
                     deadline_ms: Optional[float] = None
                     ) -> Tuple[int, Dict[str, Any]]:
-        greq = grid_request_from_dict(payload)
-        oracle = self.service.oracle
-        reqs, scatter = oracle.stage_grid(greq)   # validates anchor/pairs
-        self._check_sweep_size("grid", len(reqs))
-        reqs = [_with_deadline(r, deadline_ms) for r in reqs]
-        srs = [await f for f in self._admit(reqs)]
+        with obs.span("transport.decode") as sp:
+            greq = grid_request_from_dict(_decode_json(body))
+            oracle = self.service.oracle
+            # validates anchor/pairs
+            reqs, scatter = oracle.stage_grid(greq)
+            self._check_sweep_size("grid", len(reqs))
+            reqs = [_with_deadline(r, deadline_ms) for r in reqs]
+            futs = self._admit(reqs, sp)
+        srs = [await f for f in futs]
         for sr in srs:
             if sr.error is not None:
                 return _error_payload(sr.error)
@@ -581,43 +625,29 @@ class TransportServer:
         return 200, {"ok": True, "grid": grid.to_dict(),
                      "epochs": sorted({sr.result.epoch for sr in srs})}
 
-    async def _advise(self, payload: Any,
+    async def _advise(self, body: bytes,
                       deadline_ms: Optional[float] = None
                       ) -> Tuple[int, Dict[str, Any]]:
-        if not isinstance(payload, dict):
-            raise MalformedRequestError(
-                f"advise payload must be a JSON object, "
-                f"got {type(payload).__name__}")
-        try:
-            anchor = str(payload["anchor"])
-            w = payload["workload"]
-            workload = Workload(model=str(w["model"]),
-                                batch=int(w["batch"]), pix=int(w["pix"]))
-            profile = payload.get("profile")
-            if profile is not None:
-                profile = {str(k): float(v) for k, v in profile.items()}
-            measured = payload.get("measured_ms")
-            measured = None if measured is None else float(measured)
-            targets = payload.get("targets")
-            targets = None if targets is None else [str(t) for t in targets]
-        except ApiError:
-            raise
-        except (KeyError, TypeError, ValueError, AttributeError) as e:
-            raise MalformedRequestError(f"bad advise payload: {e!r}") from e
-        if measured is not None and self.calibrator is not None:
-            # a client that measured its own anchor latency just handed us
-            # live ground truth for the (anchor, anchor) measured-mode pair
-            # — feed the calibrator for free (never fail the sweep over it)
-            try:
-                self.calibrator.ingest(anchor, anchor, workload, measured)
-            except Exception:
-                pass
-        oracle = self.service.oracle
-        reqs, scatter = oracle.stage_advise(anchor, workload, profile,
-                                            measured, targets)
-        self._check_sweep_size("advise", len(reqs))
-        reqs = [_with_deadline(r, deadline_ms) for r in reqs]
-        srs = [await f for f in self._admit(reqs)]
+        with obs.span("transport.decode") as sp:
+            anchor, workload, profile, measured, targets = \
+                advise_args_from_dict(_decode_json(body))
+            if measured is not None and self.calibrator is not None:
+                # a client that measured its own anchor latency just handed
+                # us live ground truth for the (anchor, anchor) measured-mode
+                # pair — feed the calibrator for free (never fail the sweep
+                # over it)
+                try:
+                    self.calibrator.ingest(anchor, anchor, workload,
+                                           measured)
+                except Exception:
+                    pass
+            oracle = self.service.oracle
+            reqs, scatter = oracle.stage_advise(anchor, workload, profile,
+                                                measured, targets)
+            self._check_sweep_size("advise", len(reqs))
+            reqs = [_with_deadline(r, deadline_ms) for r in reqs]
+            futs = self._admit(reqs, sp)
+        srs = [await f for f in futs]
         for sr in srs:
             if sr.error is not None:
                 return _error_payload(sr.error)

@@ -7,12 +7,13 @@ import threading
 import numpy as np
 import pytest
 
-from repro import api
+from repro import api, obs
 from repro.api import executor
 from repro.api.bank import BankUnsupportedError, ModelBank
 from repro.core import workloads
 from repro.core.predictor import ProfetConfig
-from repro.core.regressors import RandomForestRegressor, bucket
+from repro.core.regressors import (DNNRegressor, RandomForestRegressor,
+                                   bucket)
 from repro.kernels import forest_eval
 from repro.serve import LatencyService, synthetic_requests
 
@@ -234,6 +235,62 @@ def test_grouped_pallas_interpret_matches_grouped_numpy(n_trees, d):
         X, gid, s["feat"], thr32, s["left"], s["right"], s["value"],
         depth=s["depth"])
     np.testing.assert_array_equal(v_np.astype(np.float32), v_pl)
+
+
+def _counters_moved(before):
+    after = obs.snapshot()["counters"]
+    return {k: v - before["counters"].get(k, 0) for k, v in after.items()}
+
+
+def test_grouped_pallas_launch_counts_its_upload_and_fill():
+    """One interpret-mode launch over a tiny stack: ``bank.h2d_bytes``
+    moves by the summed bytes of the host arrays the launch receives (block
+    group and depth vectors, the transposed rows, the padded stack), and
+    the fill counters by the rows and the launched slots."""
+    _, s = _toy_forest_stack(seed=3, n_trees=11, d=13)
+    G, T, N = s["feat"].shape
+    lanes = forest_eval.LANES
+    n = lanes + 37
+    gid = np.zeros(n, np.int64)
+    gid[lanes + 5:] = 2                      # group 0 fills two blocks
+    X = np.random.default_rng(4).uniform(-2, 2, size=(n, 13))
+    before = obs.snapshot()
+    forest_eval.leaf_values_grouped_pallas(
+        X, gid, s["feat"], s["thr"], s["left"], s["right"], s["value"],
+        depth=s["depth"])
+    moved = _counters_moved(before)
+    n_blocks = bucket(3)                     # 2 blocks + 1 block, bucketed
+    t_pad = -(-T // 8) * 8
+    n_pad = -(-N // lanes) * lanes
+    d_pad = 16
+    want = (2 * 4 * n_blocks                 # block_gid, block_depth
+            + 4 * d_pad * n_blocks * lanes   # xt
+            + 5 * 4 * G * t_pad * n_pad)     # five padded stack arrays
+    assert moved["bank.h2d_bytes"] == want
+    assert moved["bank.forest_rows"] == n
+    assert moved["bank.forest_slots"] == n_blocks * lanes
+
+
+def test_bank_wave_spans_its_members_and_counts_the_mlp_upload(dnn_oracle):
+    """A banked wave runs each member once under its span; the MLP apply
+    uploads its bucketed input block and head indices, nothing else (the
+    stacked heads already live on the device)."""
+    bank = dnn_oracle.bank
+    X = np.random.default_rng(5).uniform(0, 1, size=(9, bank.n_features))
+    gids = np.array([0, 0, 1, 1, 1, 2, 0, 1, 2]) % bank.n_groups
+    before = obs.snapshot()
+    bank.execute(X, gids)
+    bank.interpolate(["batch", "pixel"], np.array([0, 1]),
+                     np.array([48.0, 96.0]), np.ones(2), np.full(2, 3.0))
+    after = obs.snapshot()
+    for name in ("bank.forest", "bank.mlp", "bank.phase2"):
+        assert after["spans"][name]["n"] \
+            - before["spans"].get(name, {"n": 0})["n"] == 1, name
+    g_pad = bucket(len(np.unique(gids)))
+    r_pad = bucket(int(np.bincount(gids).max()),
+                   DNNRegressor.PREDICT_BUCKET_MIN)
+    assert _counters_moved(before).get("bank.h2d_bytes", 0) == \
+        4 * g_pad + 4 * g_pad * r_pad * bank.n_features
 
 
 def test_leaf_values_depth_bound_matches_unbounded():

@@ -10,11 +10,12 @@ import time
 import numpy as np
 import pytest
 
-from repro import api
+from repro import api, obs
 from repro.core import workloads
 from repro.core.predictor import ProfetConfig
 from repro.serve import (BackgroundServer, Client, LatencyService,
                          TransportError, replay, synthetic_requests)
+from repro.serve.transport import request_to_dict
 
 # deterministic float64 members: socket responses must match the direct
 # in-process answers to ~exact
@@ -534,6 +535,88 @@ def test_mid_traffic_swap_zero_stale_epoch_responses(oracle, oracle2,
         assert svc.stats.epoch_swaps == 1
     finally:
         bg.stop()
+
+
+def _span_moves(before, after):
+    return {name: row["n"] - before["spans"].get(name, {"n": 0})["n"]
+            for name, row in after["spans"].items()}
+
+
+def test_predict_round_trip_moves_each_layer_span_once(oracle, stream):
+    """One /predict, a cache miss, passes each layer once: decode, queue
+    wait, planner, resolve and encode; /statsz carries the totals."""
+    svc = LatencyService(oracle, max_wave=32)
+    bg = BackgroundServer(svc, batch_window_s=0.0).start()
+    try:
+        before = obs.snapshot()
+        with _client(bg) as c:
+            c.predict(stream[0])
+            trace = c.statsz()["stats"]["trace"]
+    finally:
+        bg.stop()
+    moved = _span_moves(before, trace)
+    for name in ("transport.decode", "transport.encode",
+                 "transport.resolve", "latency_service.queue_wait",
+                 "planner.plan", "latency_service.wave"):
+        assert moved[name] == 1, name
+    assert moved["executor.execute"] >= 1
+    row = trace["spans"]["transport.decode"]
+    assert row["total_s"] > 0 and row["self_s"] <= row["total_s"]
+
+
+def test_cache_hit_waits_out_its_wave_before_it_is_resolved(
+        oracle, stream, monkeypatch):
+    """A hit completes before its wave plans and executes, but its future
+    is resolved only after the whole wave: its completion-to-resolution
+    lag holds the wave's execute time."""
+    from repro.api import oracle as oracle_mod
+    slow_s = 0.08
+    execute_plans = oracle_mod.execute_plans
+
+    def slow_execute_plans(*a, **kw):
+        time.sleep(slow_s)
+        return execute_plans(*a, **kw)
+    monkeypatch.setattr(oracle_mod, "execute_plans", slow_execute_plans)
+    hit = stream[0]
+    miss = next(r for r in stream[1:] if r != hit)
+    svc = LatencyService(oracle, max_wave=32)
+    bg = BackgroundServer(svc, batch_window_s=0.0).start()
+    try:
+        with _client(bg) as c:
+            c.predict(hit)                       # now cached
+        bg.server.pause()
+        before = obs.snapshot()
+        out = {}
+
+        def fire(name, req):
+            with _client(bg) as c:
+                out[name] = c.request("POST", "/predict",
+                                      request_to_dict(req))
+        threads = [threading.Thread(target=fire, args=a)
+                   for a in (("hit", hit), ("miss", miss))]
+        for t in threads:
+            t.start()
+        deadline = time.time() + 10
+        while svc.pending() < 2 and time.time() < deadline:
+            time.sleep(0.005)
+        assert svc.pending() == 2
+        hits = svc.stats.cache_hits
+        bg.server.resume()
+        for t in threads:
+            t.join(timeout=30)
+        after = obs.snapshot()
+    finally:
+        bg.stop()
+    assert svc.stats.cache_hits == hits + 1
+    assert out["hit"][0] == 200 and out["miss"][0] == 200
+    moved = {name: {k: row[k] - before["spans"].get(
+                 name, {"n": 0, "total_s": 0.0})[k]
+                 for k in ("n", "total_s")}
+             for name, row in after["spans"].items()}
+    assert moved["latency_service.wave"]["n"] == 1
+    assert moved["transport.resolve"]["n"] == 2
+    # the miss completes after the execute, so the lag is the hit's wait
+    assert moved["transport.resolve"]["total_s"] >= slow_s
 
 
 def test_serve_public_exports():
