@@ -13,7 +13,14 @@ collapses the per-group loop:
     vector. A wave's rows — any mix of pairs — route through
     ``kernels.forest_eval.predict_grouped`` in ONE launch (Pallas grid over
     (row-block, tree-tile) on TPU, a single depth-bounded grouped traversal
-    with per-group early exit on CPU).
+    with per-group early exit on CPU). Where the forest backend is Pallas
+    the bank pads the stack into the kernel layout and places it on the
+    device once, at its warm-up or first wave, whichever comes first
+    (``forest_eval.device_forest_stack``, counted by
+    ``bank.forest_stack_uploads``); every launch passes that device stack,
+    so only the wave's rows and block vectors cross to the chip. The host
+    ``forest`` dict stays as it was: ``split`` and ``to_payload`` read it,
+    and the shard payload stays all numpy (its workers run numpy).
   - **DNN stack** — all heads' params in one vmapped pytree (leading group
     axis) with stacked z-score/target-scale stats; a wave pays ONE
     ``_mlp_apply_multi`` call on a ``(groups, rows, features)`` block,
@@ -32,13 +39,17 @@ every run.
 
 Banks are derived state: build one from a fitted ``Profet`` and swap it
 atomically with the oracle that owns it (``LatencyOracle.bank``,
-``LatencyService.oracle_refreshed``). Ensembles carrying non-production
+``LatencyService.oracle_refreshed``). The device forest stack lives and
+dies with its bank: a refit or promotion builds a new bank, which places
+its own stack; a ``split`` sub-bank or a payload-built bank starts
+without one. Ensembles carrying non-production
 members (e.g. the frozen ``repro.core.reference`` models used by the
 oracle-equivalence suite) raise :class:`BankUnsupportedError` and the
 executor falls back to the per-group path.
 """
 from __future__ import annotations
 
+import threading
 import time
 from typing import Dict, Iterable, Optional, Sequence, Tuple
 
@@ -103,6 +114,8 @@ class ModelBank:
         self.backend = backend
         self.forest_launches = 0
         self.mlp_applies = 0
+        self._forest_stack = None     # device layout, on first Pallas use
+        self._forest_stack_lock = threading.Lock()
 
     @property
     def n_groups(self) -> int:
@@ -119,6 +132,20 @@ class ModelBank:
 
     def supports(self, pairs: Iterable[Tuple[str, str]]) -> bool:
         return all(p in self.gid for p in pairs)
+
+    def device_forest_stack(self) -> Optional[tuple]:
+        """The forest stack in the Pallas kernel's layout, on the device:
+        placed once per bank, on the first call, and passed to every
+        launch. None where the forest backend is not Pallas."""
+        if self.forest is None or self.forest_backend != "pallas":
+            return None
+        with self._forest_stack_lock:
+            if self._forest_stack is None:
+                from repro.kernels import forest_eval
+                f = self.forest
+                self._forest_stack = forest_eval.device_forest_stack(
+                    f["feat"], f["thr"], f["left"], f["right"], f["value"])
+            return self._forest_stack
 
     # ------------------------------------------------------------------
     # construction
@@ -324,7 +351,8 @@ class ModelBank:
             with obs.span("bank.forest", rows=len(gids)):
                 preds.append(forest_eval.predict_grouped(
                     X, gids, f["feat"], f["thr"], f["left"], f["right"],
-                    f["value"], depth=f["depth"], backend=self.backend))
+                    f["value"], depth=f["depth"], backend=self.backend,
+                    stack=self.device_forest_stack()))
             self.forest_launches += 1
         if "dnn" in self.members:
             preds.append(self._dnn_member(X, gids))
@@ -390,9 +418,10 @@ class ModelBank:
     # ------------------------------------------------------------------
     def warmup(self, max_rows: int = 64) -> float:
         """Pre-compile every MLP bucket shape a wave up to ``max_rows``
-        rows can produce (and trigger the grouped Pallas compile when the
-        forest backend is compiled), so the first live wave after a swap
-        pays zero compiles. Returns the wall seconds spent."""
+        rows can produce (and, when the forest backend is Pallas, place the
+        device forest stack and compile the grouped launch over it), so the
+        first live wave after a swap pays zero compiles. Returns the wall
+        seconds spent."""
         t0 = time.perf_counter()
         if "dnn" in self.members and self.n_features > 0:
             import jax.numpy as jnp
@@ -421,8 +450,7 @@ class ModelBank:
         if "forest" in self.members and self.n_features > 0 \
                 and self.forest_backend == "pallas":
             from repro.kernels import forest_eval
-            f = self.forest
-            forest_eval.warm_grouped(
-                f["feat"], f["thr"], f["left"], f["right"], f["value"],
-                n_features=self.n_features, max_rows=max(max_rows, 1))
+            forest_eval.warm_grouped(self.device_forest_stack(),
+                                     n_features=self.n_features,
+                                     max_rows=max(max_rows, 1))
         return time.perf_counter() - t0

@@ -24,6 +24,17 @@ hot path: a serving wave mixing any number of (anchor, target) pairs costs
 one traversal, not one per pair. Because routing gathers and the tree-mean
 are elementwise/per-row operations, grouped answers are bit-identical to
 running each group's forest separately.
+
+The grouped Pallas launch takes its forest stack one of two ways. On the
+HOST path (no ``stack``) every launch pads the ``(G, T, N)`` arrays into
+the kernel layout (:func:`pad_forest_stack`) and hands those numpy arrays
+to the jitted kernel, which copies the whole stack to the device; the
+single-forest :func:`predict` (fit time, the per-group fallback) takes
+it. With ``stack=`` the caller passes the five padded arrays already on
+the device (:func:`device_forest_stack`, built once per stack), and only
+the block vectors and the wave's rows cross per launch. ``ModelBank``
+holds one such stack for its lifetime. Both paths launch the same kernel
+on the same values, so their answers are bit-identical.
 """
 from __future__ import annotations
 
@@ -179,6 +190,17 @@ def pad_forest_stack(feat, thr, left, right, value):
             pad(value, 0, np.float32))
 
 
+def device_forest_stack(feat, thr, left, right, value):
+    """The :func:`pad_forest_stack` layout of a ``(G, T, N)`` forest
+    stack, placed on the device once (``jax.device_put``): the ``stack=``
+    argument of the grouped launch. Counts ``bank.forest_stack_uploads``."""
+    import jax
+    stack = tuple(jax.device_put(a) for a in
+                  pad_forest_stack(feat, thr, left, right, value))
+    obs.count("bank.forest_stack_uploads", 1)
+    return stack
+
+
 def grouped_leaf_values(block_gid, block_depth, xt, feat, thr, left, right,
                         value, *, interpret: bool = False):
     """The traceable grouped kernel — a function of shapes only, so it can
@@ -275,13 +297,16 @@ def _grouped_fn():
 
 
 def leaf_values_grouped_pallas(X, gid, feat, thr, left, right, value, *,
-                               depth) -> np.ndarray:
+                               depth, stack=None) -> np.ndarray:
     """Grouped Pallas traversal: ONE launch over (row-block, tree-tile)
     pairs, float32. Rows are sorted by group and padded per group to LANES
     multiples; the block COUNT is power-of-two bucketed (padding blocks
     carry depth 0), so the launch's static shapes come from a bounded set
     and a warmed executable serves any wave mix. Returns ``(T, n_rows)``
-    in original row order."""
+    in original row order.
+
+    ``stack``: the :func:`device_forest_stack` of ``feat .. value``; the
+    launch then passes it instead of padding and uploading the stack."""
     from repro.core.regressors import bucket
 
     X = np.asarray(X)
@@ -308,8 +333,9 @@ def leaf_values_grouped_pallas(X, gid, feat, thr, left, right, value, *,
     xt = np.zeros((_round_up(d, SUBLANES), n_blocks * LANES), np.float32)
     xt[:d, pos] = X[order].T
 
-    args = (block_gid, block_depth, xt,
-            *pad_forest_stack(feat, thr, left, right, value))
+    if stack is None:
+        stack = pad_forest_stack(feat, thr, left, right, value)
+    args = (block_gid, block_depth, xt, *stack)
     # host arrays cross to the device on every launch; device arrays do not
     obs.count("bank.h2d_bytes", sum(a.nbytes for a in args
                                     if isinstance(a, np.ndarray)))
@@ -321,15 +347,14 @@ def leaf_values_grouped_pallas(X, gid, feat, thr, left, right, value, *,
     return res
 
 
-def warm_grouped(feat, thr, left, right, value, *, n_features: int,
-                 max_rows: int) -> None:
+def warm_grouped(stack, *, n_features: int, max_rows: int) -> None:
     """Compile every block-count bucket a wave of up to ``max_rows`` rows
-    can produce over this stack (each group's rows fill whole blocks, so
-    at most ``min(max_rows, G + max_rows / LANES)`` blocks)."""
+    can produce over the device ``stack`` the waves will pass (each
+    group's rows fill whole blocks, so at most
+    ``min(max_rows, G + max_rows / LANES)`` blocks)."""
     from repro.core.regressors import bucket
 
-    G = np.shape(feat)[0]
-    padded = pad_forest_stack(feat, thr, left, right, value)
+    G = stack[0].shape[0]
     d_pad = _round_up(n_features, SUBLANES)
     cap = bucket(min(max_rows, G + -(-max_rows // LANES)))
     nb = 1
@@ -337,7 +362,7 @@ def warm_grouped(feat, thr, left, right, value, *, n_features: int,
         zeros = np.zeros(nb, np.int32)
         np.asarray(_grouped_fn()(zeros, zeros,
                                  np.zeros((d_pad, nb * LANES), np.float32),
-                                 *padded))
+                                 *stack))
         nb *= 2
 
 
@@ -366,10 +391,11 @@ def predict(X, feat, thr, left, right, value, *, depth: int,
 
 
 def predict_grouped(X, gid, feat, thr, left, right, value, *, depth,
-                    backend: str = "auto") -> np.ndarray:
+                    backend: str = "auto", stack=None) -> np.ndarray:
     """Grouped forest prediction: every row routed through its own group's
     stacked forest, ONE launch + one shared float64 tree-mean. Same backend
-    policy as :func:`predict`."""
+    policy as :func:`predict`; ``stack`` (the device-resident layout of
+    ``feat .. value``) serves the Pallas launch, the numpy one ignores it."""
     if backend == "auto":
         backend = _auto_backend()
     if backend == "numpy":
@@ -377,7 +403,7 @@ def predict_grouped(X, gid, feat, thr, left, right, value, *, depth,
                                          value, depth)
     elif backend == "pallas":
         vals = leaf_values_grouped_pallas(X, gid, feat, thr, left, right,
-                                          value, depth=depth)
+                                          value, depth=depth, stack=stack)
     else:
         raise ValueError(f"unknown forest_eval backend {backend!r}")
     return tree_mean(vals)

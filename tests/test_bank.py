@@ -293,6 +293,221 @@ def test_bank_wave_spans_its_members_and_counts_the_mlp_upload(dnn_oracle):
         4 * g_pad + 4 * g_pad * r_pad * bank.n_features
 
 
+@pytest.mark.parametrize("n_trees,d", [(8, 4), (11, 13)],
+                         ids=["aligned-trees", "padded-trees-features"])
+def test_grouped_pallas_device_stack_matches_host_launch(n_trees, d):
+    """A launch over the device-resident stack (``stack=``) answers
+    bit-identically to the host-array launch and to the grouped numpy
+    traversal on a float32-quantized stack: a group spilling over two row
+    blocks, tree and feature counts that are not multiples of 8."""
+    _, s = _toy_forest_stack(seed=3, n_trees=n_trees, d=d)
+    G = s["feat"].shape[0]
+    n = forest_eval.LANES + 37
+    rng = np.random.default_rng(11)
+    X = rng.uniform(-2, 2, size=(n, d)).astype(np.float32).astype(
+        np.float64)
+    thr32 = s["thr"].astype(np.float32).astype(np.float64)
+    gid = rng.integers(0, G, size=n)
+    gid[:forest_eval.LANES + 5] = 1          # group 1 fills two row blocks
+    args = (s["feat"], thr32, s["left"], s["right"], s["value"])
+    stack = forest_eval.device_forest_stack(*args)
+    v_np = forest_eval.leaf_values_grouped_numpy(X, gid, *args, s["depth"])
+    v_host = forest_eval.leaf_values_grouped_pallas(X, gid, *args,
+                                                    depth=s["depth"])
+    v_dev = forest_eval.leaf_values_grouped_pallas(X, gid, *args,
+                                                   depth=s["depth"],
+                                                   stack=stack)
+    np.testing.assert_array_equal(v_dev, v_host)
+    np.testing.assert_array_equal(v_np.astype(np.float32), v_dev)
+    np.testing.assert_array_equal(
+        forest_eval.predict_grouped(X, gid, *args, depth=s["depth"],
+                                    backend="pallas", stack=stack),
+        forest_eval.tree_mean(v_host))
+
+
+def test_grouped_pallas_device_stack_launch_counts_only_the_rows():
+    """Placing the stack counts one ``bank.forest_stack_uploads`` and no
+    ``bank.h2d_bytes``; a launch over it counts the block vectors and the
+    transposed rows alone, and no further upload."""
+    _, s = _toy_forest_stack(seed=3, n_trees=11, d=13)
+    lanes = forest_eval.LANES
+    n = lanes + 37
+    gid = np.zeros(n, np.int64)
+    gid[lanes + 5:] = 2                      # group 0 fills two blocks
+    X = np.random.default_rng(4).uniform(-2, 2, size=(n, 13))
+    args = (s["feat"], s["thr"], s["left"], s["right"], s["value"])
+    before = obs.snapshot()
+    stack = forest_eval.device_forest_stack(*args)
+    placed = _counters_moved(before)
+    assert placed.get("bank.forest_stack_uploads") == 1
+    assert placed.get("bank.h2d_bytes", 0) == 0
+    before = obs.snapshot()
+    forest_eval.leaf_values_grouped_pallas(X, gid, *args, depth=s["depth"],
+                                           stack=stack)
+    moved = _counters_moved(before)
+    n_blocks = bucket(3)                     # 2 blocks + 1 block, bucketed
+    d_pad = 16
+    assert moved["bank.h2d_bytes"] == (2 * 4 * n_blocks
+                                       + 4 * d_pad * n_blocks * lanes)
+    assert moved.get("bank.forest_stack_uploads", 0) == 0
+    assert moved["bank.forest_rows"] == n
+    assert moved["bank.forest_slots"] == n_blocks * lanes
+
+
+def _forest_upload_bytes(gids, n_features):
+    """What a grouped launch over a device stack uploads for ``gids``:
+    the block group and depth vectors and the transposed rows."""
+    lanes = forest_eval.LANES
+    n_blocks = bucket(int((-(-np.bincount(gids) // lanes)).sum()))
+    d_pad = -(-n_features // 8) * 8
+    return 2 * 4 * n_blocks + 4 * d_pad * n_blocks * lanes
+
+
+def test_pallas_bank_places_its_stack_once(dnn_oracle):
+    """A Pallas-backend bank places its forest stack on its first wave and
+    reuses it: two waves count one upload, and their ``bank.h2d_bytes`` is
+    the rows' bytes and the MLP blocks, no stack."""
+    bank = ModelBank.build(dnn_oracle.profet, backend="pallas")
+    rng = np.random.default_rng(5)
+    waves = [np.array([0, 0, 1, 1, 1, 0, 1]) % bank.n_groups,
+             np.arange(20) % bank.n_groups]
+    before = obs.snapshot()
+    for gids in waves:
+        bank.execute(rng.uniform(0, 1, size=(len(gids), bank.n_features)),
+                     gids)
+    moved = _counters_moved(before)
+    assert moved.get("bank.forest_stack_uploads") == 1
+    want = 0
+    for gids in waves:
+        g_pad = bucket(len(np.unique(gids)))
+        r_pad = bucket(int(np.bincount(gids).max()),
+                       DNNRegressor.PREDICT_BUCKET_MIN)
+        want += (_forest_upload_bytes(gids, bank.n_features)
+                 + 4 * g_pad + 4 * g_pad * r_pad * bank.n_features)
+    assert moved["bank.h2d_bytes"] == want
+    assert bank.forest_launches == 2
+
+
+def test_pallas_bank_warmup_places_the_stack_and_covers_waves(dnn_oracle):
+    """Warm-up places the stack and compiles the grouped launch over it:
+    a wave after it compiles nothing and places no second stack."""
+    import logging
+
+    import jax
+    bank = ModelBank.build(dnn_oracle.profet, backend="pallas")
+    before = obs.snapshot()
+    bank.warmup(max_rows=64)
+    assert _counters_moved(before).get("bank.forest_stack_uploads") == 1
+    gids = np.arange(50) % bank.n_groups
+    X = np.random.default_rng(2).uniform(0, 1, size=(50, bank.n_features))
+    records = []
+    handler = logging.Handler()
+    handler.emit = records.append
+    logger = logging.getLogger("jax._src.dispatch")
+    before = obs.snapshot()
+    with jax.log_compiles(True):
+        logger.addHandler(handler)
+        try:
+            bank.execute(X, gids)
+        finally:
+            logger.removeHandler(handler)
+    compiles = [r.getMessage() for r in records
+                if "Compiling" in r.getMessage()]
+    assert not compiles, compiles
+    assert _counters_moved(before).get("bank.forest_stack_uploads", 0) == 0
+
+
+def test_pallas_banks_of_different_fits_keep_their_own_stacks(oracle):
+    """Two Pallas banks from different fits each answer from their own
+    device stack, exactly as the host-array launch over their own forest;
+    a bank split or rebuilt from its payload starts without one."""
+    ds = workloads.generate(devices=("T4", "V100", "K80"),
+                            models=("LeNet5", "AlexNet", "ResNet18"))
+    other = api.LatencyOracle.fit(
+        ds, ProfetConfig(members=("linear", "forest"), n_trees=7, seed=3))
+    banks = [ModelBank.build(o.profet, backend="pallas")
+             for o in (oracle, other)]
+    assert banks[0].pairs == banks[1].pairs
+    rng = np.random.default_rng(8)
+    X = rng.uniform(0, 1, size=(40, banks[0].n_features))
+    gids = rng.integers(0, banks[0].n_groups, size=40)
+    got = []
+    for bank in banks:
+        f = bank.forest
+        host = forest_eval.predict_grouped(
+            X, gids, f["feat"], f["thr"], f["left"], f["right"], f["value"],
+            depth=f["depth"], backend="pallas")
+        dev = forest_eval.predict_grouped(
+            X, gids, f["feat"], f["thr"], f["left"], f["right"], f["value"],
+            depth=f["depth"], backend="pallas",
+            stack=bank.device_forest_stack())
+        np.testing.assert_array_equal(dev, host)
+        got.append(bank.execute(X, gids))
+    assert banks[0].device_forest_stack() is not \
+        banks[1].device_forest_stack()
+    assert not np.allclose(got[0], got[1])
+    sub, = banks[0].split([banks[0].pairs])
+    assert sub._forest_stack is None
+    assert ModelBank.from_payload(banks[0].to_payload())._forest_stack \
+        is None
+
+
+def test_oracle_refresh_serves_from_the_new_banks_stack(monkeypatch):
+    """Across an ``oracle_refreshed`` swap on the Pallas backend every
+    answer matches the per-group path of the oracle that served it: the
+    incoming bank warms up with its own stack, and no wave after the swap
+    routes through the old one."""
+    monkeypatch.setattr(forest_eval, "_auto_backend", lambda: "pallas")
+    ds = workloads.generate(devices=("T4", "V100"),
+                            models=("LeNet5", "AlexNet"))
+    o1 = api.LatencyOracle.fit(ds, CFG)
+    o2 = api.LatencyOracle.fit(
+        ds, ProfetConfig(members=("linear", "forest"), n_trees=7, seed=3))
+    reqs = synthetic_requests(o1, n=24, seed=6)
+    plans = [o1.plan(r) for r in reqs]
+    before = obs.snapshot()
+    svc = LatencyService(o1, max_wave=8, cache_size=0, warmup_rows=16)
+    served = []
+    for o in (o1, o2):
+        if o is o2:
+            svc.oracle_refreshed(o2, fingerprint="e2")
+        subs = [svc.submit(r) for r in reqs]
+        svc.run()
+        assert all(sr.error is None for sr in subs)
+        served.append(np.array([sr.result.latency_ms for sr in subs]))
+    assert _counters_moved(before).get("bank.forest_stack_uploads") == 2
+    assert o1.bank._forest_stack is not o2.bank._forest_stack
+    for o, got in zip((o1, o2), served):
+        want = executor.execute_plans(o.profet, plans, epoch="x",
+                                      bank=None).latencies()
+        np.testing.assert_array_equal(got, want)
+    assert not np.allclose(served[0], served[1])
+
+
+def test_payload_of_a_bank_with_a_device_stack_is_numpy(dnn_oracle):
+    """The shard payload never carries the device stack: after a Pallas
+    bank has placed it, every array of ``to_payload()`` is numpy."""
+    import jax
+    bank = ModelBank.build(dnn_oracle.profet, backend="pallas")
+    assert bank.device_forest_stack() is not None
+    payload = bank.to_payload()
+    assert payload["backend"] == "numpy"
+    assert set(payload["forest"]) == set(bank.forest)
+
+    def leaves(v):
+        if isinstance(v, dict):
+            for x in v.values():
+                yield from leaves(x)
+        elif isinstance(v, (list, tuple)):
+            for x in v:
+                yield from leaves(x)
+        else:
+            yield v
+    found = list(leaves(payload))
+    assert not [v for v in found if isinstance(v, jax.Array)]
+    assert sum(isinstance(v, np.ndarray) for v in found) > 10
+
+
 def test_leaf_values_depth_bound_matches_unbounded():
     forests, _ = _toy_forest_stack(seed=5)
     f = forests[0]
