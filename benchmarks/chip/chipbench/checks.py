@@ -90,9 +90,10 @@ def as_served(endpoint: str, expected: Sequence) -> List:
 
 
 def verdict(numbers: Dict[str, float], limits: Dict[str, float]) -> bool:
-    return all(numbers[k] <= limits[k] for k in NUMBERS)
+    """Every number a cell's limits name is at or below its limit."""
+    return all(numbers[k] <= limits[k] for k in limits)
 
 
 def lines(numbers: Dict[str, float], limits: Dict[str, float]) -> List[str]:
     return [f"check {k}: {numbers[k]!r} (limit {limits[k]!r})"
-            for k in NUMBERS]
+            for k in limits]

@@ -10,6 +10,7 @@ compared with the plain reference (``reference.py``).
 """
 from __future__ import annotations
 
+import contextlib
 import gc
 import json
 import os
@@ -36,6 +37,95 @@ class NoChip(RuntimeError):
 
 def _log(line: str) -> None:
     print(line, file=sys.stderr, flush=True)
+
+
+def split_cpus(child_pid: int, allowed=None):
+    """Give the load generator ``child_pid`` a CPU of its own and keep
+    every thread of this process off it, as the clients of a deployment
+    run on other machines than its server. Of the CPUs this process may
+    use (``allowed``, by default its own affinity), the child gets the
+    last one and this process the rest. Returns ``(mine, child's,
+    restore)``, ``restore`` a function that gives this process's threads
+    back what they had; with one CPU nothing is split and it returns
+    ``None``."""
+    allowed = sorted(os.sched_getaffinity(0) if allowed is None
+                     else allowed)
+    if len(allowed) < 2:
+        return None
+    mine, theirs = set(allowed[:-1]), {allowed[-1]}
+    os.sched_setaffinity(child_pid, theirs)
+    before = {}
+    for tid in map(int, os.listdir("/proc/self/task")):
+        try:
+            before[tid] = os.sched_getaffinity(tid)
+            os.sched_setaffinity(tid, mine)
+        except OSError:             # the thread has ended meanwhile
+            before.pop(tid, None)
+
+    def restore():
+        for tid, cpus in before.items():
+            try:
+                os.sched_setaffinity(tid, cpus)
+            except OSError:
+                pass
+    return mine, theirs, restore
+
+
+@contextlib.contextmanager
+def quiet_collector():
+    """Collect once and freeze what this process holds (the bank, the
+    generated traffic, the reference's data), so that no collection in the
+    block walks it; yield the list of the collector's pauses in the block,
+    each ``[generation, start, seconds]``. What the block allocates is
+    collected as usual."""
+    gc.collect()
+    gc.freeze()
+    pauses: list = []
+
+    def on_gc(phase, info):
+        if phase == "start":
+            pauses.append([info["generation"], time.monotonic(), 0.0])
+        elif pauses:
+            pauses[-1][2] = time.monotonic() - pauses[-1][1]
+    gc.callbacks.append(on_gc)
+    try:
+        yield pauses
+    finally:
+        gc.callbacks.remove(on_gc)
+        gc.unfreeze()
+
+
+def pauses_line(pauses, w0: float, w1: float) -> str:
+    """The collector's pauses that began inside ``[w0, w1]``, for the log."""
+    inside = [p for p in pauses if w0 <= p[1] <= w1]
+    return (f"collections in the window: {len(inside)}, of them "
+            f"{sum(p[0] == 2 for p in inside)} full, longest "
+            f"{max([p[2] for p in inside] or [0]) * 1e3:.3f} ms")
+
+
+def memory_peak(devs) -> int:
+    """The peak device memory of the fullest of ``devs``: the buffers'
+    peak (``peak_bytes_in_use``) plus the peak of what the runtime reserves
+    apart for compiled programs' temporaries (``peak_bytes_reserved``),
+    which the TPU runtime does not count as in use and holds once
+    reserved."""
+    def peak(d):
+        st = d.memory_stats() or {}
+        return (st.get("peak_bytes_in_use", 0)
+                + st.get("peak_bytes_reserved", 0))
+    return int(max(peak(d) for d in devs))
+
+
+def per_second(times, t0: float, seconds: float, weights=None) -> list:
+    """Sum of ``weights`` (1 each by default) of the events at ``times``
+    in each whole second from ``t0``, over ``seconds``."""
+    n = max(1, int(round(seconds)))
+    out = [0] * n
+    for i, t in enumerate(times):
+        k = int((t - t0) // 1.0)
+        if 0 <= k < n and t <= t0 + seconds:
+            out[k] += 1 if weights is None else weights[i]
+    return out
 
 
 def process_start() -> float:
@@ -174,11 +264,15 @@ class Session:
             batch_window_s=svc["batch_window_s"]).start()
 
     def _other_configs(self) -> list:
+        """Every other bank configuration of the benchmark; a training
+        configuration fits nothing."""
         out = []
         for entry in self.bench["configs"]:
             if entry["name"] != self.cfg["name"]:
                 data = (spec.ROOT / entry["file"]).read_bytes()
-                out.append((json.loads(data), data))
+                cfg = json.loads(data)
+                if spec.kind(cfg) == "bank":
+                    out.append((cfg, data))
         return out
 
     def _prepare(self, others: list) -> None:
@@ -227,12 +321,21 @@ class Session:
         child = subprocess.Popen(
             [sys.executable, str(spec.BENCH / "loadgen" / "driver.py")],
             stdin=subprocess.PIPE, stdout=subprocess.PIPE, text=True)
+        split = None
         try:
-            out = _drive(child, gen, bodies, self.server, trace, trace_dir)
+            split = split_cpus(child.pid)
+            if split is not None:
+                self.log(f"CPUs: this process {sorted(split[0])}, the load "
+                         f"generator {sorted(split[1])}")
+            with quiet_collector() as pauses:
+                out = _drive(child, gen, bodies, self.server, trace,
+                             trace_dir)
         finally:
             if child.poll() is None:
                 child.kill()
             child.wait()
+            if split is not None:
+                split[2]()
             jax.monitoring.unregister_event_duration_listener(on_event)
             if saved is not None:
                 spans.uninstall(saved)
@@ -254,7 +357,8 @@ class Session:
              "records": [_record(gen["endpoint"], *r)
                          for r in out["records"]],
              "compiles_in_window": sum(w0 <= t <= w1 for t in compiles),
-             "compiles": len(compiles), "spans": records}
+             "compiles": len(compiles), "spans": records,
+             "pauses": pauses}
         if trace:
             m["extract"] = tracereduce.extract(str(trace_dir),
                                                spans.HOST_SPANS)
@@ -262,8 +366,7 @@ class Session:
         return m
 
     def memory_peak(self) -> int:
-        return int(max((d.memory_stats() or {}).get("peak_bytes_in_use", 0)
-                       for d in self.devs[:self.cell["chips"]]))
+        return memory_peak(self.devs[:self.cell["chips"]])
 
     def close(self) -> None:
         """Stop the server and drop the program's state."""
@@ -286,10 +389,19 @@ def run(cell_name: str, seed: int, seconds: float, trace: bool, *,
         t_start: Optional[float] = None, log: Callable[[str], None] = _log,
         **session_kw) -> dict:
     """Run ``cell_name`` once and return the result line's object.
-    ``session_kw`` go to :class:`Session`: the repository's files by
-    default; a test passes its own configuration (and may change traffic
-    parameters), with ``require_chip=False`` and no compile cache."""
+    ``session_kw`` go to :class:`Session` (a training cell's to
+    ``trainstep.TrainSession``): the repository's files by default; a test
+    passes its own configuration (and may change traffic parameters), with
+    ``require_chip=False`` and no compile cache."""
     t_start = process_start() if t_start is None else t_start
+    bench = spec.benchmark()
+    cfg = session_kw.get("cfg") or spec.load_json(
+        spec.ROOT / spec.config_entry(
+            bench, spec.cell(bench, cell_name)["config"])["file"])
+    if spec.kind(cfg) == "train":
+        from chipbench import trainstep
+        return trainstep.run(cell_name, seed, seconds, trace,
+                             t_start=t_start, log=log, **session_kw)
     s = Session(cell_name, log=log, **session_kw)
     try:
         m = s.window(seed, seconds, trace)
@@ -311,6 +423,14 @@ def run(cell_name: str, seed: int, seconds: float, trace: bool, *,
         log(f"generator lateness: p50 {percentile(late, 0.5):.3f} ms, "
             f"p99 {percentile(late, 0.99):.3f} ms, max {max(late):.3f} ms "
             f"over {len(late)} requests")
+    done = [r for r in recs if r["status"] == 200]
+    log(("rows" if m["gen"]["endpoint"] == "advise" else "answers")
+        + " finished in each second of the window: "
+        + " ".join(map(str, per_second(
+            [r["finish"] for r in done], m["w0"], seconds,
+            [len(r["body"]) if m["gen"]["endpoint"] == "advise" else 1
+             for r in done]))))
+    log(pauses_line(m["pauses"], m["w0"], m["w1"]))
     red = m.get("trace")
     if red is not None:
         device["busy_s"] = red["busy_s"]

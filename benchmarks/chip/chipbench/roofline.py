@@ -32,3 +32,20 @@ def dnn_flops(n_features: int, layers: Sequence[int]) -> int:
 
 def linear_flops(n_features: int) -> int:
     return 2 * (n_features + 1)
+
+
+def train_step_flops(layers: int, d: int, heads: int, kv_heads: int,
+                     head_dim: int, ff: int, vocab: int, batch: int,
+                     seq: int, causal: bool = True) -> int:
+    """Model FLOPs of one training step of a dense GQA decoder with a tied
+    head: 6 per weight of every matrix product per token (forward, and the
+    backward's two products), plus the attention's two products (scores
+    and the weighted sum) three times over, of which a causal mask needs
+    half. The embedding's lookup is no product, and recomputation (remat)
+    is not counted."""
+    tokens = batch * seq
+    weights = layers * (2 * d * heads * head_dim + 2 * d * kv_heads * head_dim
+                        + 3 * d * ff) + vocab * d
+    attention = layers * batch * 2 * 2 * seq * seq * heads * head_dim
+    return 6 * weights * tokens + 3 * (attention // 2 if causal
+                                       else attention)

@@ -48,6 +48,12 @@ def config_entry(bench: dict, name: str) -> dict:
     raise KeyError(f"no configuration {name!r} in BENCHMARK.json")
 
 
+def kind(cfg: dict) -> str:
+    """``train`` for a training-step configuration; a PROFET bank's file
+    leaves ``kind`` out and is ``bank``."""
+    return cfg.get("kind", "bank")
+
+
 def traffic(name: str) -> dict:
     return load_json(BENCH / "traffic" / f"{name}.json")
 

@@ -5,11 +5,13 @@ the chip; it is handed only the generated requests.
     python3 driver.py    # spec as one JSON line on stdin
 
 One thread and one ``selectors`` loop over non-blocking keep-alive
-connections, so the generator's own lateness stays small and is measured.
+connections, so the generator's own lateness stays small and is measured
+(a second thread only waits for the parent's ``GO``).
 The conversation with the parent, on stdin and stdout:
 
 1. the parent writes the spec (requests, schedule, loop) as one line;
-2. the driver runs the warm phase, lets it drain and prints ``READY``;
+2. the driver runs the warm phase and prints ``READY``: an open loop's
+   warm phase is drained first, a closed loop runs on (below);
 3. the parent writes ``GO``; the driver reads ``/statsz``, runs the
    window, reads ``/statsz`` again at its close and prints ``DONE``;
 4. the driver waits for what is still in flight (at most ``drain_s``) and
@@ -21,7 +23,10 @@ Open loop: request ``i`` is due at the window's start plus its offset and
 is sent then on an idle connection (pipelined on the least busy one when
 none is idle); its latency runs from the due time. Closed loop: each of
 ``clients`` connections sends the next request of the pool the moment its
-last one returns, until the window closes.
+last one returns, from the warm phase's start until the window closes,
+so the window opens on a loop in its steady state and not on a refill.
+Its requests are those in flight at the window's start and those sent
+after it.
 """
 from __future__ import annotations
 
@@ -31,6 +36,7 @@ import json
 import selectors
 import socket
 import sys
+import threading
 import time
 
 STATSZ = b"GET /statsz HTTP/1.1\r\nHost: bench\r\n\r\n"
@@ -213,47 +219,50 @@ def main() -> int:
     if closed:
         clients = eng.open(spec["clients"])
         nxt = [0]
-        stop = [0.0]
+        stop = [float("inf")]
 
         def on_done(c, key, now):
-            if now < stop[0] and nxt[0] < len(reqs):
+            if c is not ctl and now < stop[0] and nxt[0] < len(reqs):
                 eng.send(c, nxt[0], reqs[nxt[0]])
                 nxt[0] += 1
         eng.on_done = on_done
-
-        def start(until):
-            stop[0] = until
-            for c in clients:
-                if nxt[0] < len(reqs):
-                    eng.send(c, nxt[0], reqs[nxt[0]])
-                    nxt[0] += 1
     else:
         eng.open(spec["connections"])
         eng.idle = list(eng.conns)
         warm_n = len(spec["warm_due"])
 
-    # warm phase: the same traffic, then drained
+    # warm phase: the same traffic; an open loop's is drained, a closed
+    # loop runs on into the window
     t = time.monotonic() + 0.05
     if closed:
-        start(t + spec["warm_s"])
-        eng.run_until(t + spec["warm_s"], drain=True,
-                      drain_until=t + spec["warm_s"] + drain_s)
-        first_window = nxt[0]
+        for c in clients:
+            if nxt[0] < len(reqs):
+                eng.send(c, nxt[0], reqs[nxt[0]])
+                nxt[0] += 1
+        eng.run_until(t + spec["warm_s"])
     else:
         sched = [(t + d, i, reqs[i]) for i, d in enumerate(spec["warm_due"])]
         eng.run_until(t + spec["warm_s"], sched, drain=True,
                       drain_until=t + spec["warm_s"] + drain_s)
     print("READY", flush=True)
-    if sys.stdin.readline().strip() != "GO":
+    word = []
+    reader = threading.Thread(target=lambda: word.append(
+        sys.stdin.readline()), daemon=True)
+    reader.start()
+    while not word:                     # the loop serves on meanwhile
+        eng.poll(0.01)
+    if word[0].strip() != "GO":
         return 2
     before = eng.fetch_statsz(ctl)
 
     w0 = time.monotonic()
     w1 = w0 + spec["seconds"]
     if closed:
-        start(w1)
+        stop[0] = w1
+        sent_before = nxt[0]
+        carried = {k for c in eng.conns if not c.dead for k in c.inflight}
         eng.run_until(w1)
-        keys = range(first_window, nxt[0])
+        keys = sorted(carried) + list(range(sent_before, nxt[0]))
         due = {}
     else:
         due = {warm_n + j: w0 + d for j, d in enumerate(spec["window_due"])}

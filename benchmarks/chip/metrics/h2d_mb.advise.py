@@ -1,8 +1,9 @@
 """h2d_mb.advise: bytes of host arrays handed to the bank's jitted launches
-per wave, in MB: the padded forest stack, the rows' features and the block
-indices of each forest launch, and the input block and head indices of each
-MLP apply; an array already on the device counts nothing (``bank.h2d_bytes``
-in /statsz ``trace``, over ``latency_service.wave``, over the window)."""
+per wave, in MB: the rows' features and the block indices of each forest
+launch, and the input block and head indices of each MLP apply; an array
+already on the device, such as the padded forest stack the bank keeps
+there, counts nothing (``bank.h2d_bytes`` in /statsz ``trace``, over
+``latency_service.wave``, over the window)."""
 
 
 def read(ctx):

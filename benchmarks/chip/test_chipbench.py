@@ -55,11 +55,15 @@ def test_every_part_is_found_by_its_name():
         assert c["file"].startswith(BENCH["paths"][0] + "/")
         assert json.loads((spec.ROOT / c["file"]).read_text())["name"] \
             == c["name"]
+    from chipbench import trainstep
     for w in BENCH["workloads"]:
-        assert spec.traffic(w["traffic"])["endpoint"] in ("predict",
-                                                          "advise")
+        cfg = spec.load_json(spec.ROOT / spec.config_entry(
+            BENCH, w["config"])["file"])
+        train = spec.kind(cfg) == "train"
+        assert spec.traffic(w["traffic"])["endpoint"] in (
+            ("train",) if train else ("predict", "advise"))
         assert set(spec.cell_settings(w["name"])["limits"]) \
-            == set(checks.NUMBERS)
+            == set(trainstep.NUMBERS if train else checks.NUMBERS)
         assert w["name"] == f"{w['config']}.{w['traffic']}"
         assert spec.end_to_end(BENCH, w["name"])
         assert spec.per_layer(BENCH, w["name"])
@@ -356,6 +360,39 @@ def test_open_loop_sends_on_time_and_times_from_the_due_time():
     # the last request waited behind seven others served one at a time
     assert lat[-1] > 4 * _SlowHandler.delay_s
     assert out["w0"] <= recs[0][1] and out["w1"] >= recs[-1][1]
+
+
+def test_closed_loop_runs_on_from_the_warm_phase_into_the_window():
+    """The closed loop is not drained before the window: the window opens
+    with each client's request in flight, those requests are the window's
+    first, and no request is sent after the window closes."""
+    srv = socketserver.ThreadingTCPServer(("127.0.0.1", 0), _SlowHandler)
+    srv.daemon_threads = True
+    threading.Thread(target=srv.serve_forever, daemon=True).start()
+    try:
+        spec_ = {"host": "127.0.0.1", "port": srv.server_address[1],
+                 "loop": "closed", "clients": 2, "warm_s": 0.3,
+                 "seconds": 0.5, "drain_s": 5.0,
+                 "requests": [["/advise", "{}"]] * 200}
+        p = subprocess.run([sys.executable,
+                            str(HERE / "loadgen" / "driver.py")],
+                           input=json.dumps(spec_) + "\nGO\n",
+                           capture_output=True, text=True, timeout=60)
+        lines = p.stdout.splitlines()
+        assert lines[:3] == ["READY", "DONE"] + lines[2:3]
+        out = json.loads(lines[2])
+    finally:
+        srv.shutdown()
+        srv.server_close()
+    recs = out["records"]
+    assert all(r[4] == 200 for r in recs) and not out["exhausted"]
+    keys = [r[0] for r in recs]
+    assert keys == sorted(set(keys)) and keys[0] > 0   # after the warm phase
+    carried = [r for r in recs if r[2] < out["w0"]]
+    assert len(carried) == 2 and recs[:2] == carried
+    assert keys[2:] == list(range(keys[1] + 1, keys[1] + len(keys) - 1))
+    assert all(r[3] >= out["w0"] for r in carried)
+    assert all(r[2] <= out["w1"] for r in recs)
 
 
 # ---------------------------------------------------------------------------
