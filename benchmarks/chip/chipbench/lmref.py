@@ -1,12 +1,19 @@
-"""Plain reference of a dense GQA decoder's training step, in float32.
+"""The dense GQA decoder's module of the training harness's interface
+(``trainstep``): the plain float32 reference of its training step, and
+what the harness needs beside it to build the program's step and read it
+(:func:`dims`, :func:`program_config`, ``PROGRAM_PATHS``, ``VOCAB_AXES``,
+:func:`step_flops`). A training configuration names this file under
+``"reference"``.
 
-It imports nothing of the program. It states the arithmetic the way the
-published Llama-style description does: RMSNorm before attention and
-before the MLP, rotary positions on queries and keys (the two halves of
-each head rotated against each other), causal attention whose heads share
-key/value heads in groups, a SwiGLU MLP, a final RMSNorm, an output head
-tied to the embedding table, the mean next-token cross entropy, and AdamW
-with global-norm clipping, linear warm-up and decoupled weight decay.
+The reference imports nothing of the program; only :func:`program_config`,
+which builds the program's side, imports ``repro``, inside the function.
+The reference states the arithmetic the way the published Llama-style
+description does: RMSNorm before attention and before the MLP, rotary
+positions on queries and keys (the two halves of each head rotated against
+each other), causal attention whose heads share key/value heads in groups,
+a SwiGLU MLP, a final RMSNorm, an output head tied to the embedding table,
+the mean next-token cross entropy, and AdamW with global-norm clipping,
+linear warm-up and decoupled weight decay.
 
 Where Granite-3.0's published form departs from Llama's, this follows
 the Llama form, as the program does: no embedding multiplier (Granite:
@@ -42,9 +49,22 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from chipbench import roofline
+
 HIGHEST = jax.lax.Precision.HIGHEST
 STACKED = ("wq", "wk", "wv", "wo", "wi", "wu", "wd", "ln1", "ln2")
 LEAVES = ("tok",) + STACKED + ("final_norm",)
+# the program's parameter tree, by the reference's leaf names
+PROGRAM_PATHS = {
+    "tok": ("embed", "tok"), "wq": ("blocks", "attn", "wq"),
+    "wk": ("blocks", "attn", "wk"), "wv": ("blocks", "attn", "wv"),
+    "wo": ("blocks", "attn", "wo"), "wi": ("blocks", "ffn", "wi"),
+    "wu": ("blocks", "ffn", "wu"), "wd": ("blocks", "ffn", "wd"),
+    "ln1": ("blocks", "ln1"), "ln2": ("blocks", "ln2"),
+    "final_norm": ("final_norm",)}
+# the leaves whose vocabulary axis the program pads past the vocabulary
+# (with zeros), by that axis
+VOCAB_AXES = {"tok": 0}
 
 
 @dataclasses.dataclass(frozen=True)
@@ -72,6 +92,48 @@ class Adam:
     decay_steps: int
     min_lr_ratio: float
     decay: Tuple[str, ...]          # leaves weight decay applies to
+
+
+def dims(cfg: dict) -> Dims:
+    """The reference's sizes from the configuration file."""
+    return Dims(
+        vocab=cfg["vocab_size"], d=cfg["hidden_size"],
+        layers=cfg["num_hidden_layers"], heads=cfg["num_attention_heads"],
+        kv_heads=cfg["num_key_value_heads"], head_dim=cfg["head_dim"],
+        ff=cfg["intermediate_size"], eps=cfg["rms_norm_eps"],
+        rope_theta=cfg["rope_theta"])
+
+
+def program_config(cfg: dict):
+    """The program's model configuration for ``cfg``: its registered
+    architecture with the depth and numerics the file states, checked
+    against every width the file gives."""
+    from repro.configs import base
+    job = cfg["job"]
+    mc = dataclasses.replace(
+        base.get_config(cfg["arch"]),
+        num_layers=cfg["num_hidden_layers"], dtype=job["compute_dtype"],
+        param_dtype=job["param_dtype"], remat=job["remat"] != "none",
+        remat_policy=job["remat"])
+    want = {"d_model": cfg["hidden_size"], "d_ff": cfg["intermediate_size"],
+            "num_heads": cfg["num_attention_heads"],
+            "num_kv_heads": cfg["num_key_value_heads"],
+            "head_dim": cfg["head_dim"], "vocab_size": cfg["vocab_size"],
+            "tie_embeddings": cfg["tie_word_embeddings"],
+            "norm_eps": cfg["rms_norm_eps"], "rope_theta": cfg["rope_theta"],
+            "qkv_bias": cfg["attention_bias"], "family": "dense"}
+    got = {k: getattr(mc, k) for k in want}
+    if got != want:
+        raise RuntimeError(f"the program's {cfg['arch']} is not the "
+                           f"configuration: {got} != {want}")
+    return mc
+
+
+def step_flops(dm: Dims, batch: int, seq: int) -> int:
+    """Model FLOPs of one training step of ``batch`` rows of ``seq``
+    tokens: the yardstick of ``mfu.train``."""
+    return roofline.train_step_flops(dm.layers, dm.d, dm.heads, dm.kv_heads,
+                                     dm.head_dim, dm.ff, dm.vocab, batch, seq)
 
 
 def shapes(dm: Dims) -> Dict[str, tuple]:
@@ -266,13 +328,15 @@ def adamw(params, grads, m, v, step: int, a: Adam):
     return tuple({k: o[i] for k, o in out.items()} for i in range(4))
 
 
-def leaf_norms(tree: Dict[str, jax.Array]) -> jax.Array:
+def leaf_norms(tree: Dict[str, jax.Array], leaves=LEAVES,
+               stacked=STACKED) -> jax.Array:
     """The norm of every leaf, each layer of a stacked leaf apart, in the
-    order of :func:`leaf_names`."""
+    order of :func:`leaf_names` (another model's ``leaves`` and
+    ``stacked`` ones given)."""
     parts = []
-    for k in LEAVES:
+    for k in leaves:
         x = tree[k].astype(jnp.float32)
-        if k in STACKED:
+        if k in stacked:
             parts.append(jnp.sqrt(jnp.sum(x * x, axis=tuple(
                 range(1, x.ndim)))))
         else:
@@ -280,10 +344,10 @@ def leaf_norms(tree: Dict[str, jax.Array]) -> jax.Array:
     return jnp.concatenate(parts)
 
 
-def leaf_names(dm: Dims) -> List[str]:
-    return [f"{k}.{i}" if k in STACKED else k
-            for k in LEAVES
-            for i in (range(dm.layers) if k in STACKED else [0])]
+def leaf_names(dm: Dims, leaves=LEAVES, stacked=STACKED) -> List[str]:
+    return [f"{k}.{i}" if k in stacked else k
+            for k in leaves
+            for i in (range(dm.layers) if k in stacked else [0])]
 
 
 def readings(key, batches: Sequence[Tuple], dm: Dims, a: Adam,
@@ -293,13 +357,22 @@ def readings(key, batches: Sequence[Tuple], dm: Dims, a: Adam,
     the first step's clipped gradient (``grad``) and of the change of the
     parameters after the last step (``change``). The weights, moments and
     one gradient are all it holds on the device at a time."""
-    init = jax.jit(lambda k: init_weights(k, dm))
-    grad_fn = jax.jit(jax.value_and_grad(
-        lambda p, t, y: loss(p, t, y, dm, num)))
+    return train_readings(key, batches, lambda k: init_weights(k, dm),
+                          lambda p, t, y: loss(p, t, y, dm, num),
+                          leaf_norms, a)
+
+
+def train_readings(key, batches: Sequence[Tuple], init_fn, loss_fn,
+                   norms_fn, a: Adam) -> Dict[str, np.ndarray]:
+    """:func:`readings` of any model: its weights ``init_fn(key)``, its
+    ``loss_fn(params, tokens, labels)`` and its per-leaf
+    ``norms_fn(tree)``, trained with :func:`adamw`."""
+    init = jax.jit(init_fn)
+    grad_fn = jax.jit(jax.value_and_grad(loss_fn))
 
     def update(p, g, m, v, step):
         p, m, v, clipped = adamw(p, g, m, v, step, a)
-        return p, m, v, leaf_norms(clipped)
+        return p, m, v, norms_fn(clipped)
     step_fn = jax.jit(update, static_argnums=4, donate_argnums=(0, 2, 3))
     p = init(key)
     m = {k: jnp.zeros_like(x) for k, x in p.items()}
@@ -314,7 +387,7 @@ def readings(key, batches: Sequence[Tuple], dm: Dims, a: Adam,
         del g
     del m, v
     def change(p, k):
-        p0 = init_weights(k, dm)
-        return leaf_norms({n: p[n] - p0[n] for n in p})
+        p0 = init_fn(k)
+        return norms_fn({n: p[n] - p0[n] for n in p})
     change = np.asarray(jax.jit(change)(p, key))
     return {"loss": np.asarray(losses), "grad": grad, "change": change}

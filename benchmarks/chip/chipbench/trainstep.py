@@ -1,10 +1,27 @@
 """One run of a training-step cell: set up, time a window of steps, check
 the first steps against the plain reference.
 
+Everything this knows of an architecture comes from one module, the file
+that the configuration's ``"reference"`` names (:func:`reference`; the
+dense decoder's is ``chipbench/lmref.py``). It provides:
+
+- ``dims(cfg)``: the reference's sizes, a ``vocab`` among them (the
+  vocabulary the rows are drawn from);
+- ``program_config(cfg)``: the program's ``ModelConfig``, the file's cut
+  applied and checked against every width the file gives;
+- ``PROGRAM_PATHS``: the program's parameter tree by the reference's leaf
+  names, and ``VOCAB_AXES``: the leaves whose vocabulary axis the program
+  pads, by that axis;
+- ``init_weights(key, dims)``, ``readings(key, batches, dims, adam,
+  numerics)``, ``leaf_names(dims)``, ``leaf_norms(tree)``, ``Adam``,
+  ``REFERENCE`` and ``CONTROL``: the reference itself;
+- ``step_flops(dims, batch, seq)``: the model FLOPs of one step, the
+  yardstick of ``mfu.train``.
+
 The program's own entry points build the job: ``repro.configs`` gives the
-model (the depth cut as the configuration file says), ``launch.steps``
-the train step and the optimizer's settings, ``train.optimizer`` the
-state. The benchmark makes the weights (``lmref.init_weights``, one jitted
+model (as the module's ``program_config`` cuts it), ``launch.steps`` the
+train step and the optimizer's settings, ``train.optimizer`` the state.
+The benchmark makes the weights (the module's ``init_weights``, one jitted
 call from ``--seed``) and the token rows (uniform over the vocabulary,
 drawn on the device from ``--seed``), and hands both to the program.
 
@@ -16,34 +33,32 @@ clipped gradient per leaf (from Adam's first moment, ``m / (1 - b1)``)
 and the parameters' change per leaf after the last of them. The window
 then runs further steps back to back, each waited on for its loss, the
 host queueing the next step before it waits, as a training loop does.
-Once it has closed, the program's state is freed and ``lmref`` trains the same
-weights on the same rows in float32.
+Once it has closed, the program's state is freed and the reference trains
+the same weights on the same rows in float32.
 """
 from __future__ import annotations
 
 import dataclasses
 import gc
+import importlib.util
 import os
 import pathlib
 import shutil
+import sys
 import time
 from typing import Callable, Dict, Optional
 
 import numpy as np
 
-from chipbench import checks, harness, lmref, roofline, spec, tracereduce
+from chipbench import checks, harness, spec, tracereduce
 
 # two, not three: at the cell's size the reference's third step would
 # take it past the window's length
 CHECKED_STEPS = 2
-# the program's parameter tree, by the reference's leaf names
-PROGRAM_PATHS = {
-    "tok": ("embed", "tok"), "wq": ("blocks", "attn", "wq"),
-    "wk": ("blocks", "attn", "wk"), "wv": ("blocks", "attn", "wv"),
-    "wo": ("blocks", "attn", "wo"), "wi": ("blocks", "ffn", "wi"),
-    "wu": ("blocks", "ffn", "wu"), "wd": ("blocks", "ffn", "wd"),
-    "ln1": ("blocks", "ln1"), "ln2": ("blocks", "ln2"),
-    "final_norm": ("final_norm",)}
+# what an architecture's module provides (the module docstring says what)
+INTERFACE = ("dims", "program_config", "PROGRAM_PATHS", "VOCAB_AXES",
+             "init_weights", "readings", "leaf_names", "leaf_norms", "Adam",
+             "REFERENCE", "CONTROL", "step_flops")
 HOST_SPANS = ("chipbench.feed", "chipbench.step", "chipbench.wait")
 NUMBERS = ("loss_gap", "grad_gap", "change_gap")
 # a leaf whose reference gradient is below this share of the median
@@ -51,20 +66,44 @@ NUMBERS = ("loss_gap", "grad_gap", "change_gap")
 STILL_LEAF = 1e-3
 
 
-def dims(cfg: dict) -> lmref.Dims:
-    return lmref.Dims(
-        vocab=cfg["vocab_size"], d=cfg["hidden_size"],
-        layers=cfg["num_hidden_layers"], heads=cfg["num_attention_heads"],
-        kv_heads=cfg["num_key_value_heads"], head_dim=cfg["head_dim"],
-        ff=cfg["intermediate_size"], eps=cfg["rms_norm_eps"],
-        rope_theta=cfg["rope_theta"])
+def reference(cfg: dict, where: str):
+    """The module that a training configuration's ``"reference"`` names
+    (a path under ``benchmarks/chip``), loaded once per process; ``where``
+    names the configuration's file in the error where the key, the file or
+    a name of :data:`INTERFACE` is missing."""
+    rel = cfg.get("reference")
+    if not rel:
+        raise KeyError(f"{where}: a training configuration names its "
+                       "reference module under \"reference\"")
+    path = (spec.BENCH / rel).resolve()
+    if not path.is_file():
+        raise FileNotFoundError(f"{where}: its reference {rel!r} is no file "
+                                f"under {spec.BENCH}")
+    name = f"chipbench_train_reference:{path}"
+    if name not in sys.modules:
+        loader = importlib.util.spec_from_file_location(name, path)
+        mod = importlib.util.module_from_spec(loader)
+        # a module's dataclasses look their module up while it loads
+        sys.modules[name] = mod
+        try:
+            loader.loader.exec_module(mod)
+        except BaseException:
+            del sys.modules[name]
+            raise
+    ref = sys.modules[name]
+    missing = [n for n in INTERFACE if not hasattr(ref, n)]
+    if missing:
+        raise AttributeError(f"{where}: its reference {rel} lacks "
+                             f"{', '.join(missing)}")
+    return ref
 
 
-def adam(cfg: dict) -> lmref.Adam:
+def adam(cfg: dict, ref):
+    """The job's optimizer settings as the reference module's ``Adam``."""
     o = cfg["job"]["optimizer"]
-    return lmref.Adam(**{f.name: (tuple(o[f.name]) if f.name == "decay"
-                                  else o[f.name])
-                         for f in dataclasses.fields(lmref.Adam)})
+    return ref.Adam(**{f.name: (tuple(o[f.name]) if f.name == "decay"
+                                else o[f.name])
+                       for f in dataclasses.fields(ref.Adam)})
 
 
 def seed_key(seed: int, purpose: int):
@@ -74,30 +113,6 @@ def seed_key(seed: int, purpose: int):
     state = np.random.SeedSequence([int(seed) % 2**64, purpose]
                                    ).generate_state(2, np.uint32)
     return jax.random.wrap_key_data(state, impl="threefry2x32")
-
-
-def program_config(cfg: dict):
-    """The program's model configuration for ``cfg``: its registered
-    architecture with the depth and numerics the file states, checked
-    against every width the file gives."""
-    from repro.configs import base
-    job = cfg["job"]
-    mc = dataclasses.replace(
-        base.get_config(cfg["arch"]),
-        num_layers=cfg["num_hidden_layers"], dtype=job["compute_dtype"], param_dtype=job["param_dtype"],
-        remat=job["remat"] != "none", remat_policy=job["remat"])
-    want = {"d_model": cfg["hidden_size"], "d_ff": cfg["intermediate_size"],
-            "num_heads": cfg["num_attention_heads"],
-            "num_kv_heads": cfg["num_key_value_heads"],
-            "head_dim": cfg["head_dim"], "vocab_size": cfg["vocab_size"],
-            "tie_embeddings": cfg["tie_word_embeddings"],
-            "norm_eps": cfg["rms_norm_eps"], "rope_theta": cfg["rope_theta"],
-            "qkv_bias": cfg["attention_bias"], "family": "dense"}
-    got = {k: getattr(mc, k) for k in want}
-    if got != want:
-        raise RuntimeError(f"the program's {cfg['arch']} is not the "
-                           f"configuration: {got} != {want}")
-    return mc
 
 
 def footprint(compiled) -> dict:
@@ -122,21 +137,26 @@ def _get(tree, path):
     return tree
 
 
-def to_program(ref: dict, abstract) -> dict:
-    """The reference's weights in the program's tree, the embedding's rows
-    past the vocabulary (the program pads it) zero."""
+def to_program(weights: dict, abstract, ref) -> dict:
+    """The reference's weights in the program's tree, by the reference
+    module ``ref``'s map, each leaf's rows or columns past the vocabulary
+    (the program pads them) zero."""
     import jax
     import jax.numpy as jnp
     flat = jax.tree_util.tree_flatten_with_path(abstract)[0]
     by_path = {tuple(k.key for k in p): s for p, s in flat}
-    if set(by_path) != set(PROGRAM_PATHS.values()):
+    if set(by_path) != set(ref.PROGRAM_PATHS.values()):
         raise RuntimeError(f"the program's parameters are {sorted(by_path)}, "
-                           "not the dense decoder's this benchmark knows")
+                           f"not those {ref.__file__} maps: "
+                           f"{sorted(ref.PROGRAM_PATHS.values())}")
     out: dict = {}
-    for name, path in PROGRAM_PATHS.items():
-        want, x = by_path[path], ref[name]
-        if name == "tok":
-            x = jnp.pad(x, ((0, want.shape[0] - x.shape[0]), (0, 0)))
+    for name, path in ref.PROGRAM_PATHS.items():
+        want, x = by_path[path], weights[name]
+        if name in ref.VOCAB_AXES:
+            pad = [(0, 0)] * x.ndim
+            axis = ref.VOCAB_AXES[name]
+            pad[axis] = (0, want.shape[axis] - x.shape[axis])
+            x = jnp.pad(x, tuple(pad))
         if x.shape != want.shape:
             raise RuntimeError(f"{'/'.join(path)}: the program holds "
                                f"{want.shape}, the reference {x.shape}")
@@ -147,11 +167,12 @@ def to_program(ref: dict, abstract) -> dict:
     return out
 
 
-def from_program(tree, vocab: int) -> dict:
-    """The program's tree by the reference's leaf names, the padded rows
-    dropped."""
-    out = {n: _get(tree, p) for n, p in PROGRAM_PATHS.items()}
-    out["tok"] = out["tok"][:vocab]
+def from_program(tree, vocab: int, ref) -> dict:
+    """The program's tree by the reference's leaf names, the padded rows or
+    columns dropped."""
+    out = {n: _get(tree, p) for n, p in ref.PROGRAM_PATHS.items()}
+    for name, axis in ref.VOCAB_AXES.items():
+        out[name] = out[name][(slice(None),) * axis + (slice(0, vocab),)]
     return out
 
 
@@ -203,10 +224,13 @@ class TrainSession:
         self.bench = spec.benchmark()
         self.cell_name = cell_name
         self.cell = spec.cell(self.bench, cell_name)
+        where = spec.config_entry(self.bench, self.cell["config"])["file"]
         if cfg is None:
-            cfg = spec.load_json(spec.ROOT / spec.config_entry(
-                self.bench, self.cell["config"])["file"])
+            cfg = spec.load_json(spec.ROOT / where)
         self.cfg = cfg
+        # the architecture's module: the reference and all else the
+        # harness knows of the architecture
+        self.ref = ref = reference(cfg, where)
         self.traffic = dict(spec.traffic(self.cell["traffic"]))
         settings = spec.cell_settings(cell_name)
         self.traffic.update(settings.get("traffic", {}))
@@ -235,8 +259,8 @@ class TrainSession:
         from repro.launch import steps as ST
         from repro.models import model as M
         from repro.train import optimizer as OPT
-        self.dims, self.adam = dims(cfg), adam(cfg)
-        mc = program_config(cfg)
+        self.dims, self.adam = ref.dims(cfg), adam(cfg, ref)
+        mc = ref.program_config(cfg)
         o = cfg["job"]["optimizer"]
         hp = ST.make_opt_hparams(mc, **{
             k: o[k] for k in ("learning_rate", "b1", "b2", "eps",
@@ -253,7 +277,7 @@ class TrainSession:
         self.batch, self.seq = B, S
 
         def init(key):
-            params = to_program(lmref.init_weights(key, dm), abstract)
+            params = to_program(ref.init_weights(key, dm), abstract, ref)
             return params, OPT.init_state(params, hp)
 
         def feed(key, i):
@@ -262,9 +286,9 @@ class TrainSession:
             return {"tokens": t[:, :-1], "labels": t[:, 1:]}
 
         def change(params, key):
-            p0 = lmref.init_weights(key, dm)
-            now = from_program(params, dm.vocab)
-            return lmref.leaf_norms({k: now[k] - p0[k] for k in now})
+            p0 = ref.init_weights(key, dm)
+            now = from_program(params, dm.vocab, ref)
+            return ref.leaf_norms({k: now[k] - p0[k] for k in now})
 
         self._init = jax.jit(init)
         self.feed = jax.jit(feed)
@@ -278,12 +302,10 @@ class TrainSession:
         if self.footprint:
             log("compiled step: " + ", ".join(
                 f"{k} {v} bytes" for k, v in self.footprint.items()))
-        self._grad = jax.jit(lambda m: lmref.leaf_norms(
-            from_program(m, dm.vocab)) / (1.0 - hp.b1))
+        self._grad = jax.jit(lambda m: ref.leaf_norms(
+            from_program(m, dm.vocab, ref)) / (1.0 - hp.b1))
         self._change = jax.jit(change)
-        self.flops_per_step = roofline.train_step_flops(
-            dm.layers, dm.d, dm.heads, dm.kv_heads, dm.head_dim, dm.ff,
-            dm.vocab, B, S)
+        self.flops_per_step = ref.step_flops(dm, B, S)
 
     def start(self, seed: int) -> None:
         """Weights, state and rows from ``seed``; the checked steps, and
@@ -378,15 +400,18 @@ class TrainSession:
         jax.monitoring.unregister_event_duration_listener(self._on_event)
         self.free()
 
-    def check(self, numerics: lmref.Numerics = lmref.REFERENCE) -> dict:
+    def batches(self) -> list:
+        """The checked steps' ``(tokens, labels)``, drawn again."""
+        return [(b["tokens"], b["labels"]) for b in
+                (self.feed(self.dkey, i)
+                 for i in range(1, CHECKED_STEPS + 1))]
+
+    def check(self) -> dict:
         """The reference trained on the same weights and rows; the numbers
         against the program's readings."""
-        batches = [self.feed(self.dkey, i)
-                   for i in range(1, CHECKED_STEPS + 1)]
-        want = lmref.readings(self.wkey, [(b["tokens"], b["labels"])
-                                          for b in batches],
-                              self.dims, self.adam, numerics)
-        return compare(self.got, want, lmref.leaf_names(self.dims))
+        want = self.ref.readings(self.wkey, self.batches(), self.dims,
+                                 self.adam, self.ref.REFERENCE)
+        return compare(self.got, want, self.ref.leaf_names(self.dims))
 
 
 def run(cell_name: str, seed: int, seconds: float, trace: bool, *,

@@ -1,7 +1,8 @@
 """mfu.train: model FLOPs of the steps in the traced window over the
-window's seconds times the chip's peak bf16 rate. Per step
-``roofline.train_step_flops``: 6 per weight per token plus the causal
-attention's products, recomputation not counted."""
+window's seconds times the chip's peak bf16 rate. Per step the
+``step_flops`` of the module the configuration names (the dense decoder's:
+6 per weight per token plus the causal attention's products),
+recomputation not counted."""
 
 
 def read(ctx):

@@ -73,6 +73,33 @@ def test_every_part_is_found_by_its_name():
         spec.cell(BENCH, "no-such.cell")
 
 
+def test_every_training_configuration_names_a_module_of_the_interface(
+        tmp_path, monkeypatch):
+    """A training configuration's ``"reference"`` names a file under the
+    benchmark that provides all the harness asks of an architecture; a
+    missing key, file or name is an error that names the configuration's
+    file."""
+    from chipbench import trainstep
+    train = [c for c in BENCH["configs"] if spec.kind(
+        spec.load_json(spec.ROOT / c["file"])) == "train"]
+    assert train
+    for c in train:
+        cfg = spec.load_json(spec.ROOT / c["file"])
+        assert (spec.BENCH / cfg["reference"]).is_file()
+        ref = trainstep.reference(cfg, c["file"])
+        assert all(hasattr(ref, n) for n in trainstep.INTERFACE)
+        assert set(ref.VOCAB_AXES) <= set(ref.PROGRAM_PATHS)
+        assert trainstep.reference(cfg, c["file"]) is ref
+    with pytest.raises(KeyError, match="granite-3-2b.json"):
+        trainstep.reference({"kind": "train"}, "configs/granite-3-2b.json")
+    monkeypatch.setattr(spec, "BENCH", tmp_path)
+    with pytest.raises(FileNotFoundError, match="other.json"):
+        trainstep.reference({"reference": "no_such.py"}, "other.json")
+    (tmp_path / "half.py").write_text("def dims(cfg):\n    return cfg\n")
+    with pytest.raises(AttributeError, match="half.json.*program_config"):
+        trainstep.reference({"reference": "half.py"}, "half.json")
+
+
 def test_per_layer_metrics_follow_the_end_to_end_metric_they_move():
     for w in BENCH["workloads"]:
         e2e = {m["name"] for m in spec.end_to_end(BENCH, w["name"])}

@@ -91,7 +91,7 @@ def test_the_training_configuration_states_its_cut_and_job():
         == cfg["head_dim"]
     # the program's registered architecture has the widths the file
     # states, so the cut is the depth alone
-    mc = trainstep.program_config(cfg)
+    mc = trainstep.reference(cfg, entry["file"]).program_config(cfg)
     assert (mc.num_layers, mc.d_model, mc.d_ff, mc.vocab_size) \
         == (8, 2048, 8192, 49155)
     assert set(spec.cell_settings(CELL)["limits"]) == set(trainstep.NUMBERS)
@@ -172,6 +172,27 @@ def test_the_window_is_seeded_and_its_first_rows_all_differ(tmp_path,
 # ---------------------------------------------------------------------------
 # the reference, the control and the faults
 # ---------------------------------------------------------------------------
+
+def test_the_configurations_module_reads_as_lmref_called_directly(tmp_path,
+                                                                   smoke):
+    """The module the harness loads from the configuration's
+    ``"reference"`` is ``lmref``'s file, and gives the readings and the
+    FLOP count that ``lmref`` and ``roofline`` give when called directly."""
+    s = _session(tmp_path)
+    assert pathlib.Path(s.ref.__file__).resolve() \
+        == pathlib.Path(lmref.__file__).resolve()
+    s.start(9)
+    batches = s.batches()
+    via = s.ref.readings(s.wkey, batches, s.dims, s.adam, s.ref.REFERENCE)
+    direct = lmref.readings(s.wkey, batches, lmref.dims(s.cfg),
+                            trainstep.adam(s.cfg, lmref))
+    assert set(via) == set(direct) == {"loss", "grad", "change"}
+    for k in via:
+        np.testing.assert_array_equal(via[k], direct[k])
+    assert s.flops_per_step == roofline.train_step_flops(
+        3, 64, 4, 2, 16, 128, 256, 2, 64)
+    s.close()
+
 
 @pytest.mark.parametrize("fault", [None, "state_unchanged", "half_the_batch",
                                    "layer_skipped", "attention_unmasked",

@@ -1,9 +1,10 @@
 #!/usr/bin/env python3
 """Readings that set the limits of a training cell's ``correct``, at the
 cell's own size, in one process: on each seed the program's checked steps
-against the reference; on the control seeds also the control (the
-reference with float8 matrix products, in the program's place) and each
-planted fault (``chipbench/trainfaults.py``) against the same reference.
+against the reference (the module the cell's configuration names); on the
+control seeds also the control (the reference one precision lower, in the
+program's place) and each planted fault (``chipbench/trainfaults.py``)
+against the same reference.
 
     python3 benchmarks/chip/train_control.py \
         --workload granite-3-2b.train-4k --seeds 1,2,...,12 \
@@ -31,7 +32,7 @@ def main(argv=None) -> int:
     ap.add_argument("--faults", default="", help="comma-separated")
     args = ap.parse_args(argv)
     sys.path[:0] = [str(HERE), str(HERE.parents[1] / "src")]
-    from chipbench import checks, lmref, spec, trainfaults, trainstep
+    from chipbench import checks, spec, trainfaults, trainstep
 
     def log(line):
         print(line, file=sys.stderr, flush=True)
@@ -41,7 +42,8 @@ def main(argv=None) -> int:
     for f in (f for f in args.faults.split(",") if f):
         with trainfaults.planted(f):      # compiled into its session's step
             broken[f] = trainstep.TrainSession(args.workload, log=log)
-    names = lmref.leaf_names(s.dims)
+    ref = s.ref
+    names = ref.leaf_names(s.dims)
     controls = {int(x) for x in args.control_seeds.split(",") if x}
     seeds = [int(x) for x in args.seeds.split(",")]
     seeds += sorted(controls - set(seeds))
@@ -57,14 +59,13 @@ def main(argv=None) -> int:
                     got[f] = b.got
                     b.free()
             t1 = time.monotonic()
-            batches = [(b["tokens"], b["labels"]) for b in
-                       (s.feed(s.dkey, i)
-                        for i in range(1, trainstep.CHECKED_STEPS + 1))]
-            want = lmref.readings(s.wkey, batches, s.dims, s.adam)
+            batches = s.batches()
+            want = ref.readings(s.wkey, batches, s.dims, s.adam,
+                                ref.REFERENCE)
             t2 = time.monotonic()
             if seed in controls:
-                got["control"] = lmref.readings(s.wkey, batches, s.dims,
-                                                s.adam, lmref.CONTROL)
+                got["control"] = ref.readings(s.wkey, batches, s.dims,
+                                              s.adam, ref.CONTROL)
             out = {"seed": seed, "program_s": t1 - t0, "reference_s": t2 - t1,
                    "loss": want["loss"].tolist(), "limits": limits}
             for k, v in got.items():
